@@ -46,6 +46,8 @@ def main() -> None:
     ap.add_argument("--artifact-dir", default=".",
                     help="where to write BENCH_serve.json")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import (bench_compile_stats, bench_kernels,
                             bench_llm_serve, bench_membw, bench_pointcloud,

@@ -12,6 +12,7 @@ from repro.core.expr import arr, const, for_, var
 from repro.core.offload import compile_program, evaluate
 from repro.targets.llm import isax_int8_matvec
 from repro.kernels.ops import register_kernel_intrinsics
+from repro.launch.compile_cache import enable_compile_cache
 
 register_kernel_intrinsics()
 
@@ -45,6 +46,7 @@ VARIANTS = {
 
 
 def main():
+    enable_compile_cache()
     ix = isax_int8_matvec()
     rng = np.random.default_rng(0)
     base_env = dict(Wq=rng.integers(-127, 127, size=(5, 7)).astype(np.int8),

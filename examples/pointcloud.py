@@ -23,6 +23,7 @@ from repro.core.offload import compile_program, evaluate
 from repro.targets import isax_library
 from repro.pointcloud import ref
 from repro.pointcloud.ops import register_pointcloud_intrinsics
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def software_side():
@@ -95,6 +96,7 @@ def system_side():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     software_side()
     hardware_side()
     system_side()

@@ -19,6 +19,7 @@ from repro.core.offload import compile_program, evaluate
 from repro.targets import isax_library
 from repro.core.synthesis import synthesize
 from repro.kernels.ops import register_kernel_intrinsics
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def hardware_side():
@@ -102,6 +103,7 @@ def system_side():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     hardware_side()
     software_side()
     system_side()

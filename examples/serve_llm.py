@@ -14,6 +14,7 @@ import numpy as np
 from repro.compile import VALID_BACKENDS, LoweringConfig
 from repro.configs.base import reduced
 from repro.configs.registry import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.engine import ContinuousEngine, ServeEngine
 from repro.serve.scheduler import Request
 from repro.train import checkpoint as ckpt
@@ -24,7 +25,8 @@ def main():
     ap.add_argument("--arch", default="llama110m")
     ap.add_argument("--backend", default=None, choices=VALID_BACKENDS,
                     help="kernel lowering backend (default: "
-                         "REPRO_ATTENTION_IMPL env or 'xla')")
+                         "REPRO_ATTENTION_IMPL env, else 'pallas' on a "
+                         "TPU and 'xla' elsewhere)")
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
@@ -35,6 +37,7 @@ def main():
                          "both)")
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

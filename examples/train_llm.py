@@ -14,6 +14,7 @@ import os
 
 from repro.configs.base import reduced
 from repro.configs.registry import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim.adamw import AdamWConfig
 from repro.train.fault_tolerance import run_with_restarts
 from repro.train.trainer import TrainConfig, Trainer
@@ -29,6 +30,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="runs/train_llm")
     ap.add_argument("--compress-grads", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

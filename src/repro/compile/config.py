@@ -3,15 +3,18 @@ and engines, replacing the old ``models.layers`` module-global impl flags.
 
 Environment overrides (``REPRO_ATTENTION_IMPL``, falling back to
 ``REPRO_BACKEND``) are read in exactly one place — this constructor — and
-only when no explicit backend is given.  Everything downstream (layers,
-model families, serve engines, launchers) receives the object; nothing else
-consults ``os.environ``.
+only when no explicit backend is given.  With neither set, the platform
+decides: compiled Pallas kernels on a TPU, the XLA reference elsewhere.
+Everything downstream (layers, model families, serve engines, launchers)
+receives the object; nothing else consults ``os.environ``.
 """
 
 from __future__ import annotations
 
 import os
 from typing import Optional
+
+import jax
 
 from repro.compile.dispatch import CompileRecord, Dispatcher, get_dispatcher
 from repro.compile.trace import OpKey
@@ -29,10 +32,12 @@ class LoweringConfig:
     """Per-model/engine lowering policy.
 
     backend:
-      'xla'              — reference jnp lowering everywhere (default)
+      'xla'              — reference jnp lowering everywhere (default off
+                           a TPU)
       'xla_chunked'      — online-softmax chunked attention in pure XLA
-      'pallas'           — compiled Pallas ISAX kernels (TPU)
-      'pallas_interpret' — Pallas kernel bodies in interpret mode (CPU tests)
+      'pallas'           — compiled Pallas ISAX kernels (default on a TPU)
+      'pallas_interpret' — Pallas kernel bodies in interpret mode (CPU
+                           tests; refused on a TPU)
 
     The backend states a *preference*; the dispatcher still decides per
     (op, shape, dtype) whether the e-graph pipeline matched an ISAX and
@@ -47,10 +52,15 @@ class LoweringConfig:
                 backend = os.environ.get(name)
                 if backend:
                     break
-            backend = backend or "xla"
+        on_tpu = jax.default_backend() == "tpu"
+        if not backend:
+            backend = "pallas" if on_tpu else "xla"
         if backend not in VALID_BACKENDS:
             raise ValueError(f"unknown backend {backend!r}; "
                              f"valid: {VALID_BACKENDS}")
+        if backend == "pallas_interpret" and on_tpu:
+            raise ValueError("backend 'pallas_interpret' runs the kernels in "
+                             "the CPU interpreter; on a TPU use 'pallas'")
         self.backend = backend
         self.interpret = backend == "pallas_interpret"
         self.dispatcher = dispatcher or get_dispatcher()

@@ -200,7 +200,11 @@ TPU_PEAK_FLOPS_BF16 = 197e12      # per chip
 TPU_HBM_BW = 819e9                # bytes/s per chip
 TPU_ICI_BW_PER_LINK = 50e9        # bytes/s per link (~)
 TPU_VMEM_BYTES = 128 * 1024 * 1024
-TPU_VMEM_BUDGET = 64 * 1024 * 1024  # usable per kernel invocation (conservative)
+#: VMEM one kernel may plan for: Mosaic's default scoped limit on v5e.  A
+#: pallas_call that needs more fails to compile ("Scoped allocation ... limit
+#: 16.00M") unless it raises ``vmem_limit_bytes``; no kernel does, so the
+#: schedules are sized to fit the default.
+TPU_VMEM_BUDGET = 16 * 1024 * 1024
 TPU_CLOCK_HZ = 1.6e9
 MXU_DIM = 128
 VPU_LANES = 8  # sublane granularity for f32

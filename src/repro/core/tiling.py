@@ -28,6 +28,20 @@ def down_pow2(n: int, cap: int) -> int:
     return d
 
 
+def tpu_block_ok(block: tuple[int, ...], dims: tuple[int, ...]) -> bool:
+    """Mosaic's block-shape rule for the two minor dims of a block.
+
+    ``block`` and ``dims`` give the last two dims of a ``BlockSpec`` block
+    and of the array it tiles (one dim for a 1-D array).  The TPU compiler
+    accepts a block whose second-minor dim is a multiple of 8 and whose
+    minor dim is a multiple of 128, each unless it spans the whole array
+    dim.  The interpreter accepts any block, so only this check says
+    whether a tile chosen on the CPU would compile for the chip.
+    """
+    mults = (8, 128)[-len(block):]
+    return all(b % m == 0 or b == d for b, d, m in zip(block, dims, mults))
+
+
 def dtype_itemsize(dtype: str) -> int:
     """Itemsize in bytes for a dtype *name*, matching ``np.dtype`` where
     possible.

@@ -6,10 +6,15 @@ Tiling and buffering come from the interface-aware synthesis flow
 across the kv loop), K/V tiles are "cold" (streamed), mirroring the paper's
 cache_hint machinery.
 
-Layout: q (B, S, H, hd), k/v (B, T, K, hd) with GQA head folding h → h // G
-in the BlockSpec index map.  Grid (B, H, nq, nk): the last grid dim iterates
-sequentially on TPU, so the running max / denominator / output accumulator
-live in VMEM scratch and are re-initialized at nk == 0.
+Layout: the public contract is q (B, S, H, hd), k/v (B, T, K, hd); the
+wrappers hand the kernels a heads-major view (B, H, S, hd) so each block is
+(1, 1, tile, hd).  Mosaic requires the last two block dims to be multiples
+of (8, 128) or the full array dims; in the (B, S, H, hd) layout a
+one-head block leaves a second-minor dim of 1, which the chip's compiler
+refuses.  GQA folds h → h // G in the BlockSpec index map.  Grid
+(B, H, nq, nk): the last grid dim iterates sequentially on TPU, so the
+running max / denominator (kept as (bq, 1) columns) and the output
+accumulator live in VMEM scratch and are re-initialized at nk == 0.
 
 This is the *unpipelined* baseline: K/V stream through BlockSpec copies.
 ``kernels.pipeline.flash_attention_pipelined`` is the burst-DMA variant
@@ -28,6 +33,20 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+
+
+def heads_major(x):
+    """(B, S, H, hd) → (B, H, S, hd): the view the flash kernels tile."""
+    return jnp.swapaxes(x, 1, 2)
+
+
+def flash_scratch(bq: int, hd: int) -> list:
+    """VMEM scratch of one q tile: running max, running denominator (both
+    (bq, 1) columns, lane-broadcast against the (bq, bk) scores) and the
+    (bq, hd) output accumulator."""
+    return [pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, hd), jnp.float32)]
 
 
 def _init_flash_scratch(m_scr, l_scr, acc_scr):
@@ -49,22 +68,22 @@ def _online_softmax_update(q, k, v, mask, sm_scale,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * sm_scale
     s = jnp.where(mask, s, NEG_INF)
-    m_prev = m_scr[...]                              # (bq,)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+    m_prev = m_scr[...]                              # (bq, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     # guard fully-masked rows (m == NEG_INF): exp(NEG_INF - NEG_INF) = 1
     # would pollute l; use alpha = exp(m_prev - m_new) with masked-safe forms.
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
-    l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+    l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     m_scr[...] = m_new
 
 
 def _finalize_flash_output(o_ref, l_scr, acc_scr):
     """Divide the accumulator by the running denominator (masked-row safe)."""
-    denom = jnp.maximum(l_scr[...], 1e-30)[:, None]
-    o_ref[0, :, 0, :] = (acc_scr[...] / denom).astype(o_ref.dtype)
+    denom = jnp.maximum(l_scr[...], 1e-30)
+    o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref,
@@ -76,10 +95,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref,
         _init_flash_scratch(m_scr, l_scr, acc_scr)
 
     _online_softmax_update(
-        q_ref[0, :, 0, :].astype(jnp.float32),       # (bq, hd)
-        k_ref[0, :, 0, :].astype(jnp.float32),       # (bk, hd)
-        v_ref[0, :, 0, :].astype(jnp.float32),       # (bk, hd)
-        mask_ref[0, :, :], sm_scale, m_scr, l_scr, acc_scr)
+        q_ref[0, 0].astype(jnp.float32),             # (bq, hd)
+        k_ref[0, 0].astype(jnp.float32),             # (bk, hd)
+        v_ref[0, 0].astype(jnp.float32),             # (bk, hd)
+        mask_ref[0], sm_scale, m_scr, l_scr, acc_scr)
 
     @pl.when(ki == n_kv - 1)
     def _finalize():
@@ -88,11 +107,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref,
 
 def _flash_kernel_int8kv(q_ref, k_ref, v_ref, ks_ref, vs_ref, mask_ref,
                          o_ref, m_scr, l_scr, acc_scr,
-                         *, sm_scale: float, n_kv: int):
+                         *, sm_scale: float, n_kv: int, n_groups: int):
     """int8-KV variant (the paper's §6.5 quantized-attention ISAX): K/V
     stream HBM→VMEM as int8 (half the DMA bytes — what the interface model
     rewards) and dequantize against per-head scales INSIDE the tile, so the
     bf16 cache is never materialized."""
+    kvh = pl.program_id(1) // n_groups
     ki = pl.program_id(3)
 
     @pl.when(ki == 0)
@@ -100,14 +120,26 @@ def _flash_kernel_int8kv(q_ref, k_ref, v_ref, ks_ref, vs_ref, mask_ref,
         _init_flash_scratch(m_scr, l_scr, acc_scr)
 
     _online_softmax_update(
-        q_ref[0, :, 0, :].astype(jnp.float32),
-        k_ref[0, :, 0, :].astype(jnp.float32) * ks_ref[0],
-        v_ref[0, :, 0, :].astype(jnp.float32) * vs_ref[0],
-        mask_ref[0, :, :], sm_scale, m_scr, l_scr, acc_scr)
+        q_ref[0, 0].astype(jnp.float32),
+        k_ref[0, 0].astype(jnp.float32) * ks_ref[kvh],
+        v_ref[0, 0].astype(jnp.float32) * vs_ref[kvh],
+        mask_ref[0], sm_scale, m_scr, l_scr, acc_scr)
 
     @pl.when(ki == n_kv - 1)
     def _finalize():
         _finalize_flash_output(o_ref, l_scr, acc_scr)
+
+
+def _tile_specs(bq: int, bk: int, hd: int, G: int, mask_b: int):
+    """BlockSpecs over the heads-major view: q/out (1,1,bq,hd), k/v
+    (1,1,bk,hd) on kv head h // G, mask (1,bq,bk)."""
+    q_spec = pl.BlockSpec((1, 1, bq, hd), lambda b, h, qi, ki: (b, h, qi, 0))
+    kv_spec = pl.BlockSpec((1, 1, bk, hd),
+                           lambda b, h, qi, ki: (b, h // G, ki, 0))
+    mask_spec = pl.BlockSpec((1, bq, bk),
+                             lambda b, h, qi, ki:
+                             (b if mask_b > 1 else 0, qi, ki))
+    return q_spec, kv_spec, mask_spec
 
 
 def flash_attention_int8kv(q, k8, v8, k_scale, v_scale, mask, *,
@@ -121,32 +153,20 @@ def flash_attention_int8kv(q, k8, v8, k_scale, v_scale, mask, *,
     bq, bk = min(block_q, S), min(block_k, T)
     assert S % bq == 0 and T % bk == 0
     nq, nk = S // bq, T // bk
-    mask_b = mask.shape[0]
-    return pl.pallas_call(
-        functools.partial(_flash_kernel_int8kv, sm_scale=sm_scale, n_kv=nk),
+    q_spec, kv_spec, mask_spec = _tile_specs(bq, bk, hd, G, mask.shape[0])
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    out = pl.pallas_call(
+        functools.partial(_flash_kernel_int8kv, sm_scale=sm_scale, n_kv=nk,
+                          n_groups=G),
         grid=(B, H, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, 1, hd), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, bk, 1, hd),
-                         lambda b, h, qi, ki, G=G: (b, ki, h // G, 0)),
-            pl.BlockSpec((1, bk, 1, hd),
-                         lambda b, h, qi, ki, G=G: (b, ki, h // G, 0)),
-            pl.BlockSpec((1,), lambda b, h, qi, ki, G=G: (h // G,)),
-            pl.BlockSpec((1,), lambda b, h, qi, ki, G=G: (h // G,)),
-            pl.BlockSpec((1, bq, bk),
-                         lambda b, h, qi, ki, mb=mask_b:
-                         (b if mb > 1 else 0, qi, ki)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, 1, hd),
-                               lambda b, h, qi, ki: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, hd), jnp.float32),
-        ],
+        in_specs=[q_spec, kv_spec, kv_spec, smem, smem, mask_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, S, hd), q.dtype),
+        scratch_shapes=flash_scratch(bq, hd),
         interpret=interpret,
-    )(q, k8, v8, k_scale, v_scale, mask)
+    )(heads_major(q), heads_major(k8), heads_major(v8), k_scale, v_scale,
+      mask)
+    return heads_major(out)
 
 
 def flash_attention(q, k, v, mask, *, sm_scale: float,
@@ -160,30 +180,14 @@ def flash_attention(q, k, v, mask, *, sm_scale: float,
     bk = min(block_k, T)
     assert S % bq == 0 and T % bk == 0, (S, bq, T, bk)
     nq, nk = S // bq, T // bk
-    mask_b = mask.shape[0]
-
-    grid = (B, H, nq, nk)
+    q_spec, kv_spec, mask_spec = _tile_specs(bq, bk, hd, G, mask.shape[0])
     out = pl.pallas_call(
         functools.partial(_flash_kernel, sm_scale=sm_scale, n_kv=nk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, 1, hd), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, bk, 1, hd),
-                         lambda b, h, qi, ki, G=G: (b, ki, h // G, 0)),
-            pl.BlockSpec((1, bk, 1, hd),
-                         lambda b, h, qi, ki, G=G: (b, ki, h // G, 0)),
-            pl.BlockSpec((1, bq, bk),
-                         lambda b, h, qi, ki, mb=mask_b:
-                         (b if mb > 1 else 0, qi, ki)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, 1, hd),
-                               lambda b, h, qi, ki: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, hd), jnp.float32),
-        ],
+        grid=(B, H, nq, nk),
+        in_specs=[q_spec, kv_spec, kv_spec, mask_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, S, hd), q.dtype),
+        scratch_shapes=flash_scratch(bq, hd),
         interpret=interpret,
-    )(q, k, v, mask)
-    return out
+    )(heads_major(q), heads_major(k), heads_major(v), mask)
+    return heads_major(out)

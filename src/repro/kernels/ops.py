@@ -2,9 +2,19 @@
 
 Each wrapper: (1) derives tile shapes from the interface-aware synthesis flow
 (``core.kernel_synth``) instead of hand-tuned constants — the paper's C1
-applied to kernel configuration; (2) pads/falls back gracefully when a shape
-can't be tiled; (3) exposes an ``interpret=`` flag so the CPU container can
-execute the kernel bodies for correctness.
+applied to kernel configuration; (2) checks those tiles against the TPU
+compiler's block rule (``core.tiling.tpu_block_ok``); (3) exposes an
+``interpret=`` flag so the CPU container can execute the kernel bodies for
+correctness.
+
+A shape whose tiles break the block rule falls back to the jnp reference
+only in interpret mode, where the tiny CPU test shapes need it.  Compiled
+for the chip (``interpret=False``) such a shape raises ``ValueError``
+naming the op and the shape, so a kernel that never ran cannot hide behind
+the reference.  The ``*_tiles`` functions are the one rounding and
+tileability rule: the dispatcher's schedulers (``targets/llm.py``) call
+them too, so the shapes they accept are exactly the ones the wrappers can
+launch.
 
 Also registers e-graph intrinsics (``core.offload``) backed by the
 interpret-mode kernels, so offloaded programs execute through the same
@@ -24,7 +34,7 @@ from repro.core.kernel_synth import (
     choose_matmul_blocks,
     choose_ssd_blocks,
 )
-from repro.core.tiling import down_pow2
+from repro.core.tiling import down_pow2, tpu_block_ok
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.int8_matmul import int8_matmul as _int8mm
@@ -37,21 +47,8 @@ from repro.kernels.pipeline import (
 from repro.kernels.rmsnorm import rmsnorm as _rmsnorm
 from repro.kernels.ssd_scan import ssd_scan as _ssd
 
-
-@functools.lru_cache(maxsize=None)
-def _flash_schedule(S: int, T: int, hd: int, dtype_bytes: int):
-    return choose_flash_blocks(S, T, hd, dtype_bytes)
-
-
-@functools.lru_cache(maxsize=None)
-def _matmul_schedule(M: int, N: int, K: int, dtype_bytes: int):
-    return choose_matmul_blocks(M, N, K, dtype_bytes=dtype_bytes)
-
-
-@functools.lru_cache(maxsize=None)
-def _ssd_schedule(S: int, H: int, P: int, N: int):
-    return choose_ssd_blocks(S, H, P, N)
-
+#: Row tile of the RMSNorm kernel (rounded down to a divisor of the rows).
+RMSNORM_BLOCK_ROWS = 256
 
 # Back-compat aliases (one release): the tile/routing helpers are public
 # now — ``repro.core.tiling.down_pow2`` and ``kernels.pipeline.use_pipeline``.
@@ -59,20 +56,65 @@ _use_pipeline = use_pipeline
 _down_pow2 = down_pow2
 
 
+@functools.lru_cache(maxsize=None)
+def flash_tiles(S: int, T: int, H: int, K: int, hd: int, dtype_bytes: int):
+    """``(schedule, (bq, bk))`` for one attention shape; the tiles are None
+    when GQA heads do not fold or the blocks break the TPU block rule."""
+    sched = choose_flash_blocks(S, T, hd, dtype_bytes)
+    bq = down_pow2(S, sched.block("q")[0])
+    bk = down_pow2(T, sched.block("kv")[0])
+    ok = H % K == 0 and tpu_block_ok((bq, bk), (S, T))
+    return sched, ((bq, bk) if ok else None)
+
+
+@functools.lru_cache(maxsize=None)
+def matmul_tiles(M: int, N: int, K: int, dtype_bytes: int):
+    """``(schedule, (bm, bn, bk))`` for the int8 GEMM; None tiles when a
+    block breaks the TPU block rule."""
+    sched = choose_matmul_blocks(M, N, K, dtype_bytes=dtype_bytes)
+    bm = down_pow2(M, sched.block("a")[0])
+    bn = down_pow2(N, sched.block("b")[1])
+    bk = down_pow2(K, sched.block("a")[1])
+    ok = (tpu_block_ok((bm, bk), (M, K)) and tpu_block_ok((bn, bk), (N, K))
+          and tpu_block_ok((bm, bn), (M, N)))
+    return sched, ((bm, bn, bk) if ok else None)
+
+
+@functools.lru_cache(maxsize=None)
+def ssd_tiles(S: int, H: int, P: int, N: int):
+    """``(schedule, chunk)`` for the SSD scan; None chunk when the (chunk,
+    P) block breaks the TPU block rule."""
+    sched = choose_ssd_blocks(S, H, P, N)
+    chunk = down_pow2(S, sched.block("chunk")[0])
+    return sched, (chunk if tpu_block_ok((chunk, P), (S, P)) else None)
+
+
+def rmsnorm_tiles(R: int, d: int):
+    """Row tile of the RMSNorm kernel, or None when it breaks the TPU block
+    rule (a row count with a large odd factor)."""
+    br = down_pow2(R, RMSNORM_BLOCK_ROWS)
+    return br if tpu_block_ok((br, d), (R, d)) else None
+
+
+def _untileable(op: str, shapes) -> ValueError:
+    return ValueError(f"{op}: shapes {shapes} do not tile for the TPU "
+                      f"compiler (see core.tiling.tpu_block_ok)")
+
+
 def flash_attention_gqa(q, k, v, mask, *, sm_scale: float,
                         interpret: bool = False,
                         pipelined: bool | None = None):
-    """Drop-in for layers._sdpa: synthesis-chosen tiles, ref fallback for
-    shapes the kernel can't tile (tiny smoke shapes).  ``pipelined`` routes
-    K/V streaming through the burst-DMA pipeline (None = the synthesized
-    cost-model decision)."""
+    """Drop-in for ``layers.sdpa``: synthesis-chosen tiles.  ``pipelined``
+    routes K/V streaming through the burst-DMA pipeline (None = the
+    synthesized cost-model decision)."""
     B, S, H, hd = q.shape
     T = k.shape[1]
-    sched = _flash_schedule(S, T, hd, q.dtype.itemsize)
-    bq = down_pow2(S, sched.block("q")[0])
-    bk = down_pow2(T, sched.block("kv")[0])
-    if S % bq or T % bk or H % k.shape[2]:
-        return ref.flash_attention_ref(q, k, v, mask, sm_scale=sm_scale)
+    sched, tiles = flash_tiles(S, T, H, k.shape[2], hd, q.dtype.itemsize)
+    if tiles is None:
+        if interpret:
+            return ref.flash_attention_ref(q, k, v, mask, sm_scale=sm_scale)
+        raise _untileable("flash_attention", (q.shape, k.shape))
+    bq, bk = tiles
     mask = jnp.broadcast_to(mask, (mask.shape[0], S, T))
     if use_pipeline(sched, pipelined, T // bk):
         return _flash_pipe(q, k, v, mask, sm_scale=sm_scale, block_q=bq,
@@ -89,12 +131,12 @@ def int8_matmul(x, wq, scale, *, interpret: bool = False,
     (None = the synthesized cost-model decision)."""
     M, K = x.shape
     N = wq.shape[0]
-    sched = _matmul_schedule(M, N, K, 1)
-    bm = down_pow2(M, sched.block("a")[0])
-    bn = down_pow2(N, sched.block("b")[1])
-    bk = down_pow2(K, sched.block("a")[1])
-    if M % bm or N % bn or K % bk:
-        return ref.int8_matmul_ref(x, wq, scale)
+    sched, tiles = matmul_tiles(M, N, K, 1)
+    if tiles is None:
+        if interpret:
+            return ref.int8_matmul_ref(x, wq, scale)
+        raise _untileable("int8_matmul", (x.shape, wq.shape))
+    bm, bn, bk = tiles
     if use_pipeline(sched, pipelined, K // bk):
         return _int8mm_pipe(x, wq, scale, block_m=bm, block_n=bn,
                             block_k=bk, depth=max(2, sched.buffering),
@@ -110,10 +152,11 @@ def ssd_scan(x, dt, A, B, C, *, interpret: bool = False,
     synthesized cost-model decision)."""
     BT, H, S, P = x.shape
     N = B.shape[-1]
-    sched = _ssd_schedule(S, H, P, N)
-    chunk = down_pow2(S, sched.block("chunk")[0])
-    if S % chunk:
-        return ref.ssd_scan_ref(x, dt, A, B, C)
+    sched, chunk = ssd_tiles(S, H, P, N)
+    if chunk is None:
+        if interpret:
+            return ref.ssd_scan_ref(x, dt, A, B, C)
+        raise _untileable("ssd_scan", (x.shape, B.shape))
     if use_pipeline(sched, pipelined, S // chunk):
         return _ssd_pipe(x, dt, A, B, C, chunk=chunk,
                          depth=max(2, sched.buffering), interpret=interpret)
@@ -122,8 +165,12 @@ def ssd_scan(x, dt, A, B, C, *, interpret: bool = False,
 
 def rmsnorm(x, g, *, eps: float = 1e-6, interpret: bool = False):
     """Row-blocked RMSNorm: x (R,d), g (d) → (R,d)."""
-    R = x.shape[0]
-    br = down_pow2(R, 256)
+    R, d = x.shape
+    br = rmsnorm_tiles(R, d)
+    if br is None:
+        if interpret:
+            return ref.rmsnorm_ref(x, g, eps=eps)
+        raise _untileable("rmsnorm", (x.shape,))
     return _rmsnorm(x, g, eps=eps, block_rows=br, interpret=interpret)
 
 

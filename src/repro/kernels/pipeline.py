@@ -49,6 +49,9 @@ from repro.kernels.flash_attention import (
     _finalize_flash_output,
     _init_flash_scratch,
     _online_softmax_update,
+    _tile_specs,
+    flash_scratch,
+    heads_major,
 )
 
 #: Default burst depth when a caller forces the pipeline on without a
@@ -137,9 +140,9 @@ def _flash_pipelined_kernel(q_ref, k_hbm, v_hbm, mask_ref, o_ref,
     kvh = h // n_groups
     pipe = BurstPipeline(
         streams=(
-            (lambda t: k_hbm.at[b, pl.ds(t * block_k, block_k), kvh, :],
+            (lambda t: k_hbm.at[b, kvh, pl.ds(t * block_k, block_k), :],
              k_buf),
-            (lambda t: v_hbm.at[b, pl.ds(t * block_k, block_k), kvh, :],
+            (lambda t: v_hbm.at[b, kvh, pl.ds(t * block_k, block_k), :],
              v_buf),
         ),
         sem=sem, n_steps=n_kv, depth=depth)
@@ -150,10 +153,10 @@ def _flash_pipelined_kernel(q_ref, k_hbm, v_hbm, mask_ref, o_ref,
 
     slot = pipe.stream_step(ki)
     _online_softmax_update(
-        q_ref[0, :, 0, :].astype(jnp.float32),      # (bq, hd)
+        q_ref[0, 0].astype(jnp.float32),            # (bq, hd)
         k_buf[slot].astype(jnp.float32),            # (bk, hd)
         v_buf[slot].astype(jnp.float32),            # (bk, hd)
-        mask_ref[0, :, :], sm_scale, m_scr, l_scr, acc_scr)
+        mask_ref[0], sm_scale, m_scr, l_scr, acc_scr)
 
     @pl.when(ki == n_kv - 1)
     def _finalize():
@@ -167,9 +170,16 @@ def flash_attention_pipelined(q, k, v, mask, *, sm_scale: float,
     """Burst-DMA flash attention: K/V tiles streamed HBM→VMEM explicitly.
 
     Same contract as ``flash_attention.flash_attention`` — q (B,S,H,hd),
-    k/v (B,T,K,hd), mask (1|B,S,T) bool → (B,S,H,hd) — but the K/V operands
-    bypass BlockSpec staging and flow through a ``depth``-deep rotating
-    buffer driven by ``BurstPipeline``.
+    k/v (B,T,K,hd), mask (1|B,S,T) bool → (B,S,H,hd), tiled over the same
+    heads-major view — but the K/V operands bypass BlockSpec staging and
+    flow through a ``depth``-deep rotating buffer driven by
+    ``BurstPipeline``.
+
+    Mosaic only slices an HBM ref whose minor dim is a multiple of 128
+    lanes, so a head dim below that is zero-padded up to it (zero lanes add
+    nothing to q·k, and the padded output lanes are dropped).  The padding
+    matches what the schedule already costs: ``choose_flash_blocks`` models
+    K/V tiles at the lane-padded head dim.
     """
     B, S, H, hd = q.shape
     T, K = k.shape[1], k.shape[2]
@@ -177,32 +187,36 @@ def flash_attention_pipelined(q, k, v, mask, *, sm_scale: float,
     bq, bk = min(block_q, S), min(block_k, T)
     assert S % bq == 0 and T % bk == 0, (S, bq, T, bk)
     nq, nk = S // bq, T // bk
-    mask_b = mask.shape[0]
-    return pl.pallas_call(
+    lanes = -(-hd // 128) * 128
+    if lanes != hd:
+        pad = [(0, 0)] * 3 + [(0, lanes - hd)]
+        q, k, v = jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
+        out = flash_attention_pipelined(
+            q, k, v, mask, sm_scale=sm_scale, block_q=bq, block_k=bk,
+            depth=depth, interpret=interpret)
+        return out[..., :hd]
+    q_spec, _, mask_spec = _tile_specs(bq, bk, hd, G, mask.shape[0])
+    out = pl.pallas_call(
         functools.partial(_flash_pipelined_kernel, sm_scale=sm_scale,
                           n_kv=nk, block_k=bk, depth=depth, n_groups=G),
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, hd), lambda b, h, qi, ki: (b, qi, h, 0)),
+            q_spec,
             pl.BlockSpec(memory_space=pltpu.ANY),   # K stays in HBM
             pl.BlockSpec(memory_space=pltpu.ANY),   # V stays in HBM
-            pl.BlockSpec((1, bq, bk),
-                         lambda b, h, qi, ki, mb=mask_b:
-                         (b if mb > 1 else 0, qi, ki)),
+            mask_spec,
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, hd),
-                               lambda b, h, qi, ki: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, S, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((depth, bk, hd), k.dtype),
             pltpu.VMEM((depth, bk, hd), v.dtype),
             pltpu.SemaphoreType.DMA((2, depth)),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, hd), jnp.float32),
+            *flash_scratch(bq, hd),
         ],
         interpret=interpret,
-    )(q, k, v, mask)
+    )(heads_major(q), heads_major(k), heads_major(v), mask)
+    return heads_major(out)
 
 
 # ---------------------------------------------------------------------------

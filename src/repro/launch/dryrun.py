@@ -94,7 +94,7 @@ def build_lowered(arch: str, shape_name: str, mesh,
         tc = TrainConfig(total_steps=10_000, warmup=100, optimizer=opt_cfg)
         step = make_train_step(model, opt_cfg, tc)
         b_shard = batch_sharding(cfg, mesh, specs["batch"])
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(step, in_shardings=(p_shard, opt_shard, b_shard),
                              donate_argnums=(0, 1))
             return jitted.lower(pspecs, opt_specs, specs["batch"])
@@ -105,7 +105,7 @@ def build_lowered(arch: str, shape_name: str, mesh,
         def prefill_fn(params, batch):
             return model.prefill(params, batch)
 
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(prefill_fn, in_shardings=(p_shard, b_shard))
             return jitted.lower(pspecs, specs["batch"])
 
@@ -117,7 +117,7 @@ def build_lowered(arch: str, shape_name: str, mesh,
     def serve_step(params, token, caches, pos):
         return model.decode_step(params, token, caches, pos)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted = jax.jit(serve_step,
                          in_shardings=(p_shard, tok_shard, c_shard,
                                        pos_shard),

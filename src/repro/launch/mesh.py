@@ -8,6 +8,7 @@ never touches jax device state — the dry-run must set
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -28,10 +29,12 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices, have {len(devices)} — set "
             "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "any jax import (launch/dryrun.py does this)")
-    return jax.make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_host_mesh():
     """Degenerate 1×1 mesh for CPU smoke tests of the sharded code path."""
     return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2,
                          devices=jax.devices()[:1])

@@ -148,7 +148,7 @@ def build_variant(arch: str, shape_name: str, mesh, variant: str):
             (n_micro, mb, S, cfg.d_model),
             mlayers.dtype_of(cfg.compute_dtype))
         x_shard = NamedSharding(mesh, P(None, "data", None, None))
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(fwd, in_shardings=(blk_shard, x_shard))
             return cfg, jitted.lower(bspecs, x_specs)
 
@@ -168,7 +168,7 @@ def build_variant(arch: str, shape_name: str, mesh, variant: str):
         p_rep = jax.tree.map(lambda _: rep, p_shard)
         o_rep = jax.tree.map(lambda _: rep, opt_specs)
         b_shard = batch_sharding(cfg, mesh, specs["batch"], "dp_only")
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(step,
                              in_shardings=(p_rep, o_rep, rep, b_shard),
                              donate_argnums=(0, 1, 2))
@@ -182,14 +182,14 @@ def build_variant(arch: str, shape_name: str, mesh, variant: str):
         tc = TrainConfig(total_steps=10_000, warmup=100, optimizer=opt_cfg)
         step = make_train_step(model, opt_cfg, tc)
         b_shard = batch_sharding(cfg, mesh, specs["batch"], policy)
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(step, in_shardings=(p_shard, opt_shard, b_shard),
                              donate_argnums=(0, 1))
             return cfg, jitted.lower(pspecs, opt_specs, specs["batch"])
 
     if shape.kind == "prefill":
         b_shard = batch_sharding(cfg, mesh, specs["batch"], policy)
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(lambda p, b: model.prefill(p, b),
                              in_shardings=(p_shard, b_shard))
             return cfg, jitted.lower(pspecs, specs["batch"])
@@ -205,7 +205,7 @@ def build_variant(arch: str, shape_name: str, mesh, variant: str):
         def serve_step(qparams, token, caches, pos):
             return model.decode_step(_dequant(qparams), token, caches, pos)
 
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(serve_step,
                              in_shardings=(q_shard, tok_shard, c_shard,
                                            pos_shard),
@@ -244,7 +244,7 @@ def build_variant(arch: str, shape_name: str, mesh, variant: str):
                                           / sv), -127, 127).astype(jnp.int8)
             return logits, out
 
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(serve_step,
                              in_shardings=(p_shard, tok_shard, qc_shard,
                                            pos_shard),
@@ -255,7 +255,7 @@ def build_variant(arch: str, shape_name: str, mesh, variant: str):
     def serve_step(params, token, caches, pos):
         return model.decode_step(params, token, caches, pos)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted = jax.jit(serve_step,
                          in_shardings=(p_shard, tok_shard, c_shard,
                                        pos_shard),

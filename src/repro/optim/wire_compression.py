@@ -79,7 +79,7 @@ def make_int8_wire_train_step(model, opt_cfg: AdamWConfig, mesh,
     build with batch leading dim divisible by the DP size.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     def step(params, opt_state, err_flat, batch):
         loss, grads = jax.value_and_grad(model.loss)(params, batch)
@@ -98,7 +98,7 @@ def make_int8_wire_train_step(model, opt_cfg: AdamWConfig, mesh,
         step, mesh=mesh,
         in_specs=(pspec, pspec, pspec, bspec),
         out_specs=(pspec, pspec, pspec, pspec),
-        check_rep=False)
+        check_vma=False)
 
 
 def init_err_state(params) -> jnp.ndarray:

@@ -2,9 +2,9 @@
 
 Mirrors ``kernels/ops.py`` for the LLM ops: tile shapes and burst-pipeline
 depth come from the interface-aware synthesis flow (``core.kernel_synth``),
-shapes the kernels can't tile fall back to the jnp references, and each
-wrapper exposes ``interpret=`` so the CPU container executes the real
-kernel bodies.  ``pipelined=`` overrides the synthesized go/no-go decision
+shapes the kernels can't tile fall back to the jnp references in interpret
+mode and raise ``ValueError`` when compiled, and each wrapper exposes
+``interpret=`` so the CPU container executes the real kernel bodies.  ``pipelined=`` overrides the synthesized go/no-go decision
 (None = trust the cost model).
 
 Also registers e-graph intrinsics for the ``fps`` / ``ball_query`` /
@@ -66,15 +66,23 @@ def pc_tiles(M: int, N: int, sched, stream_key: str):
     return bm, bn
 
 
+def _no_kernel(op: str, shapes) -> ValueError:
+    return ValueError(f"{op}: shapes {shapes} have no kernel launch "
+                      f"(the dispatcher's scheduler declines them)")
+
+
 def farthest_point_sample(xyz, n_samples: int, *, interpret: bool = False):
-    """FPS: xyz (B, N, d) → indices (B, n_samples) i32 (ref fallback when
-    asked for more samples than points, or when the cloud exceeds VMEM —
-    FPS has no tiling to shrink)."""
+    """FPS: xyz (B, N, d) → indices (B, n_samples) i32.  Asked for more
+    samples than points, or for a cloud that exceeds VMEM (FPS has no
+    tiling to shrink), it falls back to the reference in interpret mode and
+    raises ``ValueError`` when compiled."""
     B, N, d = xyz.shape
     if (n_samples > N
             or fps_vmem_bytes(N, n_samples,
                               xyz.dtype.itemsize) > TPU_VMEM_BUDGET):
-        return pcref.fps_ref(xyz, n_samples)
+        if interpret:
+            return pcref.fps_ref(xyz, n_samples)
+        raise _no_kernel("fps", (xyz.shape, n_samples))
     _fps_schedule(N, n_samples, xyz.dtype.itemsize)  # recorded by dispatch
     return pck.fps(xyz, n_samples, interpret=interpret)
 
@@ -92,8 +100,10 @@ def ball_query(xyz, centers, radius: float, k: int, *,
     sched = _ball_schedule(M, N, k, xyz.dtype.itemsize)
     tiles = pc_tiles(M, N, sched, "x")
     if tiles is None:
-        return pcref.ball_query_ref(xyz, centers, radius, k,
-                                    radius_sq=radius_sq)
+        if interpret:
+            return pcref.ball_query_ref(xyz, centers, radius, k,
+                                        radius_sq=radius_sq)
+        raise _no_kernel("ball_query", (xyz.shape, centers.shape))
     bm, bn = tiles
     if use_pipeline(sched, pipelined, N // bn):
         return pck.ball_query_pipelined(
@@ -114,7 +124,9 @@ def group_aggregate(features, idx, *, interpret: bool = False,
     sched = _group_schedule(M, N, k, C, features.dtype.itemsize)
     tiles = pc_tiles(M, N, sched, "f")
     if tiles is None:
-        return pcref.group_aggregate_ref(features, idx)
+        if interpret:
+            return pcref.group_aggregate_ref(features, idx)
+        raise _no_kernel("group_aggregate", (features.shape, idx.shape))
     bm, bn = tiles
     if use_pipeline(sched, pipelined, N // bn):
         return pck.group_aggregate_pipelined(

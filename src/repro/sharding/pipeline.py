@@ -19,7 +19,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -85,7 +85,7 @@ def gpipe(
     in_specs = (P(stage_axis), P(None, data_axes, None, None))
     out_specs = P(None, data_axes, None, None)
     return shard_map(run, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+                     out_specs=out_specs, check_vma=False)
 
 
 def reference_forward(stage_fn, params_stacked, x_micro, n_stages: int):

@@ -18,14 +18,9 @@ from typing import TYPE_CHECKING
 import numpy as np
 
 from repro.core.expr import Term, arr, const, for_, var
-from repro.core.kernel_synth import (
-    choose_flash_blocks,
-    choose_matmul_blocks,
-    choose_ssd_blocks,
-    pipeline_fields,
-)
+from repro.core.kernel_synth import pipeline_fields
 from repro.core.matching import ISAX
-from repro.core.tiling import down_pow2, dtype_itemsize
+from repro.core.tiling import dtype_itemsize
 from repro.kernels import ops as kops
 from repro.kernels.pipeline import (
     flash_attention_pipelined,
@@ -263,11 +258,10 @@ def _attention_schedule(key: "OpKey"):
         return None, f"degenerate query tile (S={S} < {MIN_QUERY_TILE})"
     # itemsize (not a name heuristic) so the recorded schedule matches the
     # one the kernel wrapper re-derives from q.dtype.itemsize
-    sched = choose_flash_blocks(S, T, hd, dtype_itemsize(key.dtype))
-    bq = down_pow2(S, sched.block("q")[0])
-    bk = down_pow2(T, sched.block("kv")[0])
-    if S % bq or T % bk or H % K:
+    sched, tiles = kops.flash_tiles(S, T, H, K, hd, dtype_itemsize(key.dtype))
+    if tiles is None:
         return None, f"untileable shape S={S} T={T} H={H} K={K}"
+    bq, bk = tiles
     return ({"block_q": bq, "block_k": bk, "buffering": sched.buffering,
              "est_step_cycles": sched.est_step_cycles,
              "vmem_bytes": sched.vmem_bytes,
@@ -276,26 +270,26 @@ def _attention_schedule(key: "OpKey"):
 
 def _rmsnorm_schedule(key: "OpKey"):
     rows, d = key.shape
-    return {"block_rows": down_pow2(rows, 256)}, "ok"
+    br = kops.rmsnorm_tiles(rows, d)
+    if br is None:
+        return None, f"untileable rows={rows}"
+    return {"block_rows": br}, "ok"
 
 
 def _int8_matmul_schedule(key: "OpKey"):
     M, Kd, N = key.shape
-    sched = choose_matmul_blocks(M, N, Kd, dtype_bytes=1)
-    bm = down_pow2(M, sched.block("a")[0])
-    bn = down_pow2(N, sched.block("b")[1])
-    bk = down_pow2(Kd, sched.block("a")[1])
-    if M % bm or N % bn or Kd % bk:
+    sched, tiles = kops.matmul_tiles(M, N, Kd, 1)
+    if tiles is None:
         return None, f"untileable shape M={M} N={N} K={Kd}"
+    bm, bn, bk = tiles
     return ({"block_m": bm, "block_n": bn, "block_k": bk,
              "buffering": sched.buffering, **pipeline_fields(sched)}, "ok")
 
 
 def _ssd_schedule(key: "OpKey"):
     b, s, H, P, N = key.shape
-    sched = choose_ssd_blocks(s, H, P, N)
-    chunk = down_pow2(s, sched.block("chunk")[0])
-    if s % chunk:
+    sched, chunk = kops.ssd_tiles(s, H, P, N)
+    if chunk is None:
         return None, f"untileable sequence s={s}"
     return ({"chunk": chunk, "buffering": sched.buffering,
              **pipeline_fields(sched)}, "ok")

@@ -13,7 +13,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.sharding.pipeline import gpipe, reference_forward
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 S, L, D = 4, 8, 16          # 4 stages x 2 layers
 n_micro, mb, seq = 6, 4, 8
 
@@ -29,7 +30,7 @@ def stage_fn(p, x):
     return h
 
 pipelined = gpipe(stage_fn, mesh, stage_axis="model", data_axes=("data",))
-with mesh:
+with jax.set_mesh(mesh):
     got = jax.jit(pipelined)(params, x)
 want = reference_forward(stage_fn, params, x, n_stages=4)
 err = float(jnp.abs(got - want).max())
@@ -38,7 +39,7 @@ assert err < 1e-5, err
 # differentiability: grad of a scalar loss through the pipeline
 def loss(p):
     return jnp.sum(jax.jit(pipelined)(p, x) ** 2)
-with mesh:
+with jax.set_mesh(mesh):
     g = jax.grad(loss)(params)
 def loss_ref(p):
     return jnp.sum(reference_forward(stage_fn, p, x, 4) ** 2)
