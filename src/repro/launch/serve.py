@@ -17,6 +17,7 @@ from repro.configs.registry import get_config
 from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.engine import ContinuousEngine, ServeEngine
 from repro.serve.scheduler import make_poisson_workload
+from repro.serve.telemetry import pad_share
 
 
 def main():
@@ -81,6 +82,9 @@ def main():
               f"ITL={stats.mean_itl_s * 1e3:.2f}ms "
               f"({stats.tokens_per_s:.1f} tok/s, "
               f"{stats.decode_steps} decode steps)")
+        print(f"engine: admissions={len(reqs)} "
+              f"prefill_padding={100 * pad_share(reqs):.1f}% "
+              f"decode_steps={eng.decode_steps}")
         return
 
     eng = ServeEngine(cfg, max_len=args.prompt_len + args.tokens + 8,

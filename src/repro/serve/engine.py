@@ -42,6 +42,7 @@ from repro.models.registry import Model, get_model
 from repro.serve.kv_cache import PagedKVCache
 from repro.serve.scheduler import (Request, RequestQueue, Scheduler,
                                    pick_bucket)
+from repro.serve.telemetry import install_gc_spans, span
 
 
 @dataclasses.dataclass
@@ -233,6 +234,8 @@ class ContinuousEngine:
         self.scheduler = Scheduler(max_batch)
         self.queue = RequestQueue()
         self.step_count = 0
+        self.decode_steps = 0   # decode dispatches since the engine was built
+        install_gc_spans()
         self._next_tokens = np.zeros((max_batch,), np.int32)
         # jax.jit caches one executable per prompt-bucket shape.
         self._prefill = jax.jit(
@@ -258,69 +261,59 @@ class ContinuousEngine:
         return max(bucket, req.prompt_len + req.max_new_tokens)
 
     def _admit(self, req: Request) -> None:
-        slot = self.scheduler.bind(req)
-        bucket = pick_bucket(req.prompt_len, self.prompt_buckets)
-        self.cache.bind_slot(slot, self._lifetime_tokens(req, bucket))
-        tokens = np.zeros((1, bucket), np.int32)
-        tokens[0, :req.prompt_len] = req.prompt
-        logits, kv = self._prefill(
-            self.params, {"tokens": jnp.asarray(tokens)},
-            jnp.int32(req.prompt_len))
-        self.cache.write_prefill(slot, kv, req.prompt_len)
-        first = int(jnp.argmax(logits[0]))
-        now = time.perf_counter()
-        req.out_tokens.append(first)
-        req.t_first_token = now
-        if len(req.out_tokens) >= req.max_new_tokens:
-            req.t_done = now
-        self._next_tokens[slot] = first
-        self._membership_dirty = True
-
-    def _retire_finished(self) -> None:
-        for slot in self.scheduler.finished_slots():
-            self.scheduler.retire(slot)
-            self.cache.release_slot(slot)
+        with span("engine.admit", rid=req.rid):
+            slot = self.scheduler.bind(req)
+            bucket = pick_bucket(req.prompt_len, self.prompt_buckets)
+            req.bucket = bucket
+            self.cache.bind_slot(slot, self._lifetime_tokens(req, bucket))
+            with span("engine.prefill", rid=req.rid):
+                tokens = np.zeros((1, bucket), np.int32)
+                tokens[0, :req.prompt_len] = req.prompt
+                logits, kv = self._prefill(
+                    self.params, {"tokens": jnp.asarray(tokens)},
+                    jnp.int32(req.prompt_len))
+            with span("engine.kv_write", rid=req.rid):
+                self.cache.write_prefill(slot, kv, req.prompt_len)
+            with span("engine.first_token", rid=req.rid):
+                first = int(jnp.argmax(logits[0]))
+            now = time.perf_counter()
+            req.out_tokens.append(first)
+            req.t_first_token = now
+            if len(req.out_tokens) >= req.max_new_tokens:
+                req.t_done = now
+            self._next_tokens[slot] = first
             self._membership_dirty = True
 
-    def step(self) -> bool:
-        """One scheduler iteration: retire → admit (+prefill) → decode.
-        Returns True iff a decode step actually ran."""
-        now = time.perf_counter()
-        self._retire_finished()
-        # Stamp eligibility (for TTFT) on everything that has arrived.
-        for r in self.queue:
-            if r.arrival_step <= self.step_count and r.t_eligible is None:
-                r.t_eligible = now
-        while self.scheduler.has_capacity():
-            head = self.queue.head()
-            if head is None or head.arrival_step > self.step_count:
-                break
-            bucket = pick_bucket(head.prompt_len, self.prompt_buckets)
-            if not self.cache.can_admit(self._lifetime_tokens(head, bucket)):
-                break  # FIFO head-of-line: wait for pages to free
-            req = self.queue.pop_eligible(self.step_count)
-            if req.t_eligible is None:
-                req.t_eligible = now
-            self._admit(req)
-        # A request whose budget was met at prefill (max_new_tokens == 1)
-        # must not ride through a decode dispatch.
-        self._retire_finished()
-        active = self.scheduler.active_slots
-        if active:
-            if self._membership_dirty or self._device_state is None:
+    def _retire_finished(self) -> None:
+        with span("engine.retire"):
+            for slot in self.scheduler.finished_slots():
+                self.scheduler.retire(slot)
+                self.cache.release_slot(slot)
+                self._membership_dirty = True
+
+    def _decode_active(self, active: list) -> None:
+        """One decode step of the slots in ``active``: upload the batch's
+        state if its membership changed, dispatch, wait for the new tokens
+        and hand them to their requests."""
+        if self._membership_dirty or self._device_state is None:
+            with span("engine.upload"):
                 pt, sl, act = self.cache.device_views(active)
                 # snapshot: _next_tokens is mutated after dispatch and the
                 # host→device copy is async (see device_views)
                 self._device_state = (jnp.asarray(self._next_tokens.copy()),
                                       pt, sl, act)
                 self._membership_dirty = False
-            tokens_d, pt, sl, act = self._device_state
+        tokens_d, pt, sl, act = self._device_state
+        with span("engine.decode"):
             tokens_d, self.cache.k_pages, self.cache.v_pages, sl = \
                 self._decode(self.params, tokens_d, self.cache.k_pages,
                              self.cache.v_pages, pt, sl, act)
-            self._device_state = (tokens_d, pt, sl, act)
+        self._device_state = (tokens_d, pt, sl, act)
+        self.decode_steps += 1
+        with span("engine.fetch"):
             nxt = np.asarray(tokens_d)
-            now = time.perf_counter()
+        now = time.perf_counter()
+        with span("engine.bookkeep"):
             for slot in active:
                 req = self.scheduler.slots[slot]
                 self.cache.seq_lens[slot] += 1
@@ -329,8 +322,38 @@ class ContinuousEngine:
                     if len(req.out_tokens) >= req.max_new_tokens:
                         req.t_done = now
                 self._next_tokens[slot] = nxt[slot]
-        self.step_count += 1
-        return bool(active)
+
+    def step(self) -> bool:
+        """One scheduler iteration: retire → admit (+prefill) → decode.
+        Returns True iff a decode step actually ran."""
+        with jax.profiler.StepTraceAnnotation("engine.step",
+                                             step_num=self.step_count):
+            now = time.perf_counter()
+            self._retire_finished()
+            # Stamp eligibility (for TTFT) on everything that has arrived.
+            for r in self.queue:
+                if r.arrival_step <= self.step_count and r.t_eligible is None:
+                    r.t_eligible = now
+            while self.scheduler.has_capacity():
+                head = self.queue.head()
+                if head is None or head.arrival_step > self.step_count:
+                    break
+                bucket = pick_bucket(head.prompt_len, self.prompt_buckets)
+                if not self.cache.can_admit(
+                        self._lifetime_tokens(head, bucket)):
+                    break  # FIFO head-of-line: wait for pages to free
+                req = self.queue.pop_eligible(self.step_count)
+                if req.t_eligible is None:
+                    req.t_eligible = now
+                self._admit(req)
+            # A request whose budget was met at prefill (max_new_tokens == 1)
+            # must not ride through a decode dispatch.
+            self._retire_finished()
+            active = self.scheduler.active_slots
+            if active:
+                self._decode_active(active)
+            self.step_count += 1
+            return bool(active)
 
     # -- public API --------------------------------------------------------
 
@@ -358,13 +381,13 @@ class ContinuousEngine:
         # Arrival steps are relative to workload start; a reused engine must
         # not carry a prior run's step count into the gating.
         self.step_count = 0
+        steps0 = self.decode_steps
         t0 = time.perf_counter()
-        decode_steps = 0
         while self.queue or self.scheduler.has_active():
-            decode_steps += int(self.step())
+            self.step()
         wall = time.perf_counter() - t0
         self.cache.allocator.check_leaks()
-        return _aggregate(requests, wall, decode_steps)
+        return _aggregate(requests, wall, self.decode_steps - steps0)
 
 
 class StaticBatchEngine:

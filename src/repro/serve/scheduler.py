@@ -46,6 +46,7 @@ class Request:
     arrival_step: int = 0
     # Filled in by the engine:
     out_tokens: list = dataclasses.field(default_factory=list)
+    bucket: Optional[int] = None     # padded length it was prefilled at
     t_eligible: Optional[float] = None
     t_first_token: Optional[float] = None
     t_done: Optional[float] = None
