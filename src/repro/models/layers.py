@@ -304,43 +304,68 @@ def attention_decode(params, x, cfg: ModelConfig, k_cache, v_cache, pos,
 
 
 def attention_decode_paged(params, x, cfg: ModelConfig, k_pages, v_pages,
-                           page_table, seq_lens, active,
+                           layer, page_table, seq_lens,
                            lowering: Optional[LoweringConfig] = None):
-    """One-token decode against a block-paged KV pool (vLLM-style).
+    """One-token decode against a block-paged KV pool (vLLM-style): the
+    read side.  The pool is not written here; ``write_decode_kv`` stores
+    every layer's new token after the layer scan, in place.
 
     x: (B,1,d) new-token activations for every batch slot (inactive slots
     carry dummy tokens so the batch shape is jit-stable).
-    k_pages/v_pages: (N, page, K, hd) shared page pools for this layer.
+    k_pages/v_pages: (L, N, page, K, hd) page pools of every layer; this
+    layer's pages are gathered straight from them at ``layer``.
     page_table: (B, P) int32 — logical page p of slot b lives in physical
     page ``page_table[b, p]``; unused entries may hold any valid index
     (their positions are masked).
-    seq_lens: (B,) int32 tokens already stored per slot; the new token is
-    written at logical position ``seq_lens[b]``.
-    active: (B,) bool — inactive slots write nowhere (OOB index + drop).
-    Returns (out (B,1,d), k_pages, v_pages).
+    seq_lens: (B,) int32 tokens already stored per slot; the new token sits
+    at logical position ``seq_lens[b]``, put into the gathered context.
+    Returns (out (B,1,d), k (B,K,hd), v (B,K,hd)) with k/v the new token's
+    entries in the pool's dtype.
     """
     lw = lowering or default_lowering()
     hd = cfg.resolved_head_dim()
     B = x.shape[0]
-    N, page = k_pages.shape[0], k_pages.shape[1]
-    P = page_table.shape[1]
+    T = page_table.shape[1] * k_pages.shape[2]
     positions = seq_lens[:, None].astype(jnp.int32)          # (B,1) per-slot
     q, k, v = _qkv(params, x, cfg, positions)
-    phys = page_table[jnp.arange(B), seq_lens // page]       # (B,)
-    slot = seq_lens % page
-    phys = jnp.where(active, phys, N)                        # OOB → dropped
-    k_pages = k_pages.at[phys, slot].set(k[:, 0].astype(k_pages.dtype),
-                                         mode="drop")
-    v_pages = v_pages.at[phys, slot].set(v[:, 0].astype(v_pages.dtype),
-                                         mode="drop")
-    kg = k_pages[page_table].reshape(B, P * page, *k_pages.shape[2:])
-    vg = v_pages[page_table].reshape(B, P * page, *v_pages.shape[2:])
-    mask = jnp.arange(P * page)[None, None, :] <= seq_lens[:, None, None]
+    k = k.astype(k_pages.dtype)
+    v = v.astype(v_pages.dtype)
+    here = (jnp.arange(T) == positions)[:, :, None, None]    # (B,T,1,1)
+    kg = jnp.where(here, k, k_pages[layer, page_table].reshape(
+        B, T, *k_pages.shape[3:]))
+    vg = jnp.where(here, v, v_pages[layer, page_table].reshape(
+        B, T, *v_pages.shape[3:]))
+    mask = jnp.arange(T)[None, None, :] <= seq_lens[:, None, None]
     out = sdpa(q, kg.astype(q.dtype), vg.astype(q.dtype), mask, hd, lw,
                 kind="attention_paged")
     cd = dtype_of(cfg.compute_dtype)
     return (jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(cd)),
-            k_pages, v_pages)
+            k[:, 0], v[:, 0])
+
+
+def write_decode_kv(k_pages, v_pages, k_new, v_new, page_table, seq_lens,
+                    active):
+    """The write side of paged decode: store each layer's new token at
+    logical position ``seq_lens[b]`` of slot b, one scatter per pool.
+
+    k_pages/v_pages: (L, N, page, K, hd); k_new/v_new: (L, B, K, hd).  The
+    pools are scattered as (L·N, page, K, hd) rows ``l·N + phys`` (a view of
+    the same buffer), so a donated pool is updated in place.  Inactive slots
+    write nowhere (out-of-bounds row + drop).
+    """
+    n_layers, N, page = k_pages.shape[:3]
+    B = page_table.shape[0]
+    phys = page_table[jnp.arange(B), seq_lens // page]       # (B,)
+    rows = jnp.arange(n_layers)[:, None] * N + phys[None, :]  # (L,B)
+    rows = jnp.where(active[None, :], rows, n_layers * N)    # OOB → dropped
+    slot = jnp.broadcast_to(seq_lens % page, rows.shape)
+
+    def put(pool, new):
+        flat = pool.reshape(n_layers * N, *pool.shape[2:])
+        flat = flat.at[rows, slot].set(new, mode="drop")
+        return flat.reshape(pool.shape)
+
+    return put(k_pages, k_new), put(v_pages, v_new)
 
 
 def make_mask(kind: str, S: int, T: Optional[int] = None,

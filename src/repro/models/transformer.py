@@ -207,11 +207,13 @@ def prefill_at(params, batch, length, cfg: ModelConfig,
 def decode_step_paged(params, tokens, k_pages, v_pages, page_table, seq_lens,
                       active, cfg: ModelConfig,
                       lowering: Optional[LoweringConfig] = None):
-    """One-token decode through the paged KV pools (see
-    ``layers.attention_decode_paged``).  tokens: (B,) int32; pools carry a
-    leading layer axis (L, N, page, K, hd) and are scanned alongside the
-    stacked block params so the batch/pool shapes stay constant across
-    request admissions and evictions.
+    """One-token decode through the paged KV pools.  tokens: (B,) int32;
+    pools carry a leading layer axis (L, N, page, K, hd).  The layer scan
+    only reads the pools (``layers.attention_decode_paged``) and yields each
+    layer's new K/V; one scatter per pool then writes them all in place
+    (``layers.write_decode_kv``), so the batch/pool shapes stay constant
+    across request admissions and evictions and no layer slices or restacks
+    the pool.
 
     Returns (logits (B, vocab), k_pages, v_pages).
     """
@@ -219,11 +221,11 @@ def decode_step_paged(params, tokens, k_pages, v_pages, page_table, seq_lens,
     x = L.embed(params["embed"], tokens[:, None], cfg)  # (B,1,d)
 
     def body(h, xs):
-        bp, kp, vp = xs
-        a, kp, vp = L.attention_decode_paged(
+        bp, layer = xs
+        a, k, v = L.attention_decode_paged(
             bp["attn"], L.rmsnorm(bp["attn_norm"], h, cfg.norm_eps,
                                   lowering=lw),
-            cfg, kp, vp, page_table, seq_lens, active, lowering=lw)
+            cfg, k_pages, v_pages, layer, page_table, seq_lens, lowering=lw)
         h = h + a
         if _is_moe(cfg):
             y, _ = moe_mlp(bp["moe"], L.rmsnorm(bp["mlp_norm"], h,
@@ -232,13 +234,15 @@ def decode_step_paged(params, tokens, k_pages, v_pages, page_table, seq_lens,
         else:
             y = L.mlp(bp["mlp"], L.rmsnorm(bp["mlp_norm"], h, cfg.norm_eps,
                                            lowering=lw), cfg, lowering=lw)
-        return h + y, (kp, vp)
+        return h + y, (k, v)
 
     h, (k_new, v_new) = jax.lax.scan(
-        body, x, (params["blocks"], k_pages, v_pages))
+        body, x, (params["blocks"], jnp.arange(k_pages.shape[0])))
+    k_pages, v_pages = L.write_decode_kv(k_pages, v_pages, k_new, v_new,
+                                         page_table, seq_lens, active)
     h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps, lowering=lw)
     logits = L.unembed(_unembed_table(params, cfg), h, cfg, lowering=lw)
-    return logits[:, 0], k_new, v_new
+    return logits[:, 0], k_pages, v_pages
 
 
 def decode_step(params, token, caches, pos, cfg: ModelConfig,

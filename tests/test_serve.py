@@ -8,6 +8,8 @@ import pytest
 
 from repro.configs.base import reduced
 from repro.configs.registry import get_config
+from repro.models import layers as L
+from repro.models import transformer
 from repro.models.registry import get_model
 from repro.serve.engine import ContinuousEngine, StaticBatchEngine
 from repro.serve.kv_cache import (PageAllocationError, PageAllocator,
@@ -89,6 +91,84 @@ class TestPagedNumerics:
                                   jnp.int32(PL))
         np.testing.assert_allclose(np.asarray(ref), np.asarray(got),
                                    atol=1e-5, rtol=0)
+
+
+def _scatter_then_gather_decode(params, tokens, k_pages, v_pages,
+                                page_table, seq_lens, active, cfg):
+    """Paged decode written plainly, layer by layer: scatter the new token
+    into the layer's pool, then gather the slot's pages and attend."""
+    B = tokens.shape[0]
+    N, page = k_pages.shape[1], k_pages.shape[2]
+    P = page_table.shape[1]
+    hd = cfg.resolved_head_dim()
+    phys = jnp.where(active, page_table[jnp.arange(B), seq_lens // page], N)
+    slot = seq_lens % page
+    mask = jnp.arange(P * page)[None, None, :] <= seq_lens[:, None, None]
+    h = L.embed(params["embed"], tokens[:, None], cfg)
+    ks, vs = [], []
+    for layer in range(cfg.n_layers):
+        bp = jax.tree.map(lambda a: a[layer], params["blocks"])
+        q, k, v = L._qkv(bp["attn"], L.rmsnorm(bp["attn_norm"], h,
+                                               cfg.norm_eps),
+                         cfg, seq_lens[:, None])
+        kp = k_pages[layer].at[phys, slot].set(k[:, 0], mode="drop")
+        vp = v_pages[layer].at[phys, slot].set(v[:, 0], mode="drop")
+        kg = kp[page_table].reshape(B, P * page, *kp.shape[2:])
+        vg = vp[page_table].reshape(B, P * page, *vp.shape[2:])
+        out = L._sdpa_xla(q, kg, vg, mask, hd)
+        h = h + jnp.einsum("bshk,hkd->bsd", out, bp["attn"]["wo"])
+        h = h + L.mlp(bp["mlp"], L.rmsnorm(bp["mlp_norm"], h, cfg.norm_eps),
+                      cfg)
+        ks.append(kp)
+        vs.append(vp)
+    h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps)
+    logits = L.unembed(transformer._unembed_table(params, cfg), h, cfg)
+    return logits[:, 0], jnp.stack(ks), jnp.stack(vs)
+
+
+def test_decode_paged_writes_only_the_new_tokens(cfg, model_and_params):
+    """One decode step stores each active slot's new K/V, in every layer,
+    at its page and offset; every other pool entry keeps its bits, and
+    inactive slots write nothing.  Active slots' logits match the plain
+    scatter-then-gather step (inactive slots' logits are discarded)."""
+    model, params = model_and_params
+    B, PS, MAXLEN = 5, 16, 64
+    P = MAXLEN // PS
+    N = B * P + 3
+    shape = (cfg.n_layers, N, PS, cfg.n_kv_heads, cfg.resolved_head_dim())
+    kk, kv, kt = jax.random.split(jax.random.key(7), 3)
+    k_pages = jax.random.normal(kk, shape, jnp.float32)
+    v_pages = jax.random.normal(kv, shape, jnp.float32)
+    page_table = np.random.default_rng(0).permutation(N)[:B * P]
+    page_table = page_table.reshape(B, P).astype(np.int32)
+    seq_lens = np.array([5, 16, 40, 63, 31], np.int32)
+    active = np.array([True, False, True, True, False])
+    tokens = jax.random.randint(kt, (B,), 0, cfg.vocab, jnp.int32)
+    k_before, v_before = np.asarray(k_pages), np.asarray(v_pages)
+
+    want_logits, want_k, want_v = _scatter_then_gather_decode(
+        params, tokens, k_pages, v_pages, jnp.asarray(page_table),
+        jnp.asarray(seq_lens), jnp.asarray(active), cfg)
+    logits, k_after, v_after = model.decode_paged(
+        params, tokens, k_pages, v_pages, jnp.asarray(page_table),
+        jnp.asarray(seq_lens), jnp.asarray(active))
+    k_after, v_after = np.asarray(k_after), np.asarray(v_after)
+
+    written = np.zeros(shape[:3], bool)
+    for b in np.flatnonzero(active):
+        written[:, page_table[b, seq_lens[b] // PS], seq_lens[b] % PS] = True
+    for b in np.flatnonzero(~active):
+        assert not written[:, page_table[b, seq_lens[b] // PS],
+                           seq_lens[b] % PS].any()
+    for got, before, want in ((k_after, k_before, want_k),
+                              (v_after, v_before, want_v)):
+        np.testing.assert_array_equal(got[~written], before[~written])
+        np.testing.assert_allclose(got[written], np.asarray(want)[written],
+                                   atol=1e-5, rtol=0)
+        assert not np.allclose(got[written], before[written])
+    np.testing.assert_allclose(np.asarray(logits)[active],
+                               np.asarray(want_logits)[active],
+                               atol=1e-5, rtol=0)
 
 
 # ---------------------------------------------------------------------------

@@ -13,13 +13,16 @@ do not compile yet are ``xfail(strict=True)`` with the compiler's error, so
 the change that repairs one has to flip its mark.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.compile import Dispatcher, LoweringConfig
+from repro.configs.base import ModelConfig
 from repro.configs.registry import get_config
 from repro.kernels import ops as kops
 from repro.models.registry import get_model
@@ -89,6 +92,52 @@ def test_prefill_program_compiles_at_bucket_128(one_chip):
     text = (jax.jit(model.prefill_at).lower(params, {"tokens": tokens}, length)
             .compile().as_text())
     assert "tpu_custom_call" in text
+
+
+def _unfused_ops(hlo_text):
+    """(name, opcode, dims) of every instruction outside the computations
+    that fusions call: the ops that read and write device memory."""
+    fused = set(re.findall(r" fusion\(.*?calls=%([\w.\-]+)", hlo_text))
+    ops = []
+    for block in re.split(r"\n(?=\S)", hlo_text):
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+)", block)
+        if head is None or head.group(1) in fused:
+            continue
+        ops += re.findall(r"^\s+(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* "
+                          r"([\w\-]+)\(", block, re.M)
+    return [(name, op, dims) for name, dims, op in ops]
+
+
+def test_paged_decode_updates_the_pool_in_place(one_chip):
+    """Yi-9B's widths cut to 2 layers, 16 slots, page 16, max_len 4096,
+    bf16, pools donated as the engine does: the decode step reads the pools
+    through the page table and writes the new tokens in place, so no
+    temporary holds a layer's pool and no slice, update-slice or copy
+    outputs one."""
+    cfg = ModelConfig(name="yi9b-2l", family="dense", n_layers=2,
+                      d_model=4096, n_heads=32, n_kv_heads=4, d_ff=11008,
+                      vocab=64000, head_dim=128, remat="none")
+    model = get_model(cfg, lowering=LoweringConfig("pallas", Dispatcher()))
+    B, page, max_len = 16, 16, 4096
+    n_pages = B * max_len // page
+    pool = (cfg.n_layers, n_pages, page, cfg.n_kv_heads, cfg.head_dim)
+    layer_pool_bytes = math.prod(pool[1:]) * 2
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+    params = jax.tree.map(lambda a: spec(a.shape, a.dtype),
+                          jax.eval_shape(model.init, jax.random.key(0)))
+    compiled = jax.jit(model.decode_paged, donate_argnums=(2, 3)).lower(
+        params, spec((B,), I32), spec(pool, jnp.bfloat16),
+        spec(pool, jnp.bfloat16), spec((B, max_len // page), I32),
+        spec((B,), I32), spec((B,), jnp.bool_)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_pool_bytes
+    entry = ",".join(map(str, pool[2:]))
+    moves = [(name, dims) for name, op, dims in
+             _unfused_ops(compiled.as_text())
+             if re.search(r"copy|dynamic[-_](update[-_])?slice", name + op)
+             and dims.endswith(entry)
+             and math.prod(map(int, dims.split(","))) >= math.prod(pool[1:])]
+    assert not moves
 
 
 # --- kernels off the serve path -------------------------------------------
