@@ -4,6 +4,8 @@ Everything that belongs to one configuration, traffic mix or per-layer metric
 is a file of its own, found by the name that ``BENCHMARK.json`` gives it:
 
 * ``perfbench/configs/<config>.json``  (the entry's ``file``)
+* ``perfbench/arch/<model_type>.py``  (the configuration's ``model_type``:
+  its program config, weights, reference and work counts)
 * ``perfbench/traffic/<traffic>.json``
 * ``perfbench/checks/<workload>.json``  (the limits of the correctness check)
 * ``perfbench/metrics/<stem>.py``, where ``<stem>`` is a metric's name up to
@@ -16,6 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 import importlib
+import importlib.util
 import json
 import pathlib
 import sys
@@ -39,6 +42,7 @@ class Cell:
     checks: dict
     end_to_end: list
     per_layer: list
+    arch: object
 
 
 def _load_json(path: pathlib.Path) -> dict:
@@ -59,13 +63,40 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     w = cells[name]
     cfg_entry = {c["name"]: c for c in bench["configs"]}[w["config"]]
     bench_dir = root / "perfbench"
+    config = _load_json(root / cfg_entry["file"])
     return Cell(
-        name=name, chips=int(w["chips"]),
-        config=_load_json(root / cfg_entry["file"]),
+        name=name, chips=int(w["chips"]), config=config,
         traffic=_load_json(bench_dir / "traffic" / f"{w['traffic']}.json"),
         checks=_load_json(bench_dir / "checks" / f"{name}.json"),
         end_to_end=[m for m in bench["end_to_end"] if _reports(m, name)],
-        per_layer=[m for m in bench["per_layer"] if _reports(m, name)])
+        per_layer=[m for m in bench["per_layer"] if _reports(m, name)],
+        arch=arch(config, root))
+
+
+def arch(cfg: dict, root: pathlib.Path = ROOT):
+    """The module ``perfbench/arch/<model_type>.py`` of the checkout at
+    ``root`` for a configuration file; a ``model_type`` without one is an
+    error, never a default."""
+    model_type = str(cfg.get("model_type"))
+    path = root / "perfbench" / "arch" / f"{model_type}.py"
+    if not (model_type.isidentifier() and path.is_file()):
+        raise SystemExit(
+            f"configuration {cfg.get('name')!r} has model_type "
+            f"{model_type!r} and no module for it: add "
+            f"perfbench/arch/{model_type}.py (its interface is in "
+            f"perfbench/arch/__init__.py)")
+    name = f"perfbench.arch.{model_type}"
+    if path.resolve() == (ROOT / "perfbench" / "arch" / path.name).resolve():
+        return importlib.import_module(name)
+    # A module of another checkout: keyed by its path, so that it never
+    # stands in for this checkout's module of the same name.
+    key = f"{name}@{path.resolve()}"
+    if key not in sys.modules:
+        spec = importlib.util.spec_from_file_location(key, path)
+        module = importlib.util.module_from_spec(spec)
+        sys.modules[key] = module
+        spec.loader.exec_module(module)
+    return sys.modules[key]
 
 
 def load_peaks(kind: str, root: pathlib.Path = ROOT) -> dict:
@@ -104,25 +135,6 @@ def check_device(chips: int, root: pathlib.Path = ROOT) -> tuple[dict, dict]:
         raise NoDevice(f"the cell needs {chips} chips, JAX sees "
                        f"{len(devs)}; nothing was measured")
     return stamp, load_peaks(stamp["kind"], root)
-
-
-def model_config(cfg: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
-
-    dtype = cfg["torch_dtype"]
-    return ModelConfig(
-        name=cfg["name"], family=cfg["serve"]["family"],
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-        head_dim=cfg["head_dim"], qkv_bias=cfg["attention_bias"],
-        rope_theta=float(cfg["rope_theta"]),
-        tie_embeddings=cfg["tie_word_embeddings"],
-        norm_eps=float(cfg["rms_norm_eps"]),
-        param_dtype=dtype, compute_dtype=dtype, logit_dtype="float32",
-        remat="none")
 
 
 def log(*parts) -> None:

@@ -57,7 +57,7 @@ def main(argv=None) -> int:
     for i, seed in enumerate(seeds):
         if i:
             eng.params = params = None
-            params = weights.make(cell.config, seed)
+            params = weights.make(cell.config, seed, cell.arch)
             eng.params = params
         items = serve.items_for(cell, args.seconds, seed)
         served = serve.run_schedule(eng, inst, items,
@@ -67,7 +67,8 @@ def main(argv=None) -> int:
                               mix["check_tokens"])
         controls = ("int8", "fp8") if i < args.controls else ()
         t = time.perf_counter()
-        got = check.compare(Reference(cell.config, params), picked, controls)
+        got = check.compare(Reference(cell.config, params, cell.arch), picked,
+                            controls)
         got.update(seed=seed, reference_s=time.perf_counter() - t,
                    failed=serve.attempted_failed(served)[1])
         rows.append(got)

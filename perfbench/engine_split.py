@@ -5,20 +5,19 @@ own spans.
     python perfbench/engine_split.py --workload <cell> --seed <n> --seconds <s>
 
 ``ContinuousEngine`` writes host spans named ``engine.*`` into the
-profiler's trace (``repro.serve.telemetry``); ``tracereduce.Trace`` keeps
-only the harness's.  This command serves the cell as ``run.py --trace 1``
-does (same engine, warm-up and schedule, the window's last ``trace_s``
+profiler's trace (``repro.serve.telemetry``), which ``tracereduce.Trace``
+keeps as ``engine_spans``.  This command serves the cell as ``run.py --trace
+1`` does (same engine, warm-up and schedule, the window's last ``trace_s``
 seconds traced) but makes no comparison with the reference, and prints one
 JSON object: ``engine_idle``, idle seconds by the innermost engine span
-active at each instant (``host:harness`` where none is); ``idle_gaps``, idle
-seconds by harness span, as ``run.py``'s breakdown has them; and
-``step_host_ms``, as ``metrics/step_host_ms.py`` reads it.
+active at each instant (``host:harness`` where none is), 20 entries where
+``run.py``'s breakdown has 10; ``idle_gaps``, idle seconds by harness span;
+and ``step_host_ms``, as ``metrics/step_host_ms.py`` reads it.
 """
 
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import os
 import pathlib
@@ -31,60 +30,6 @@ if __name__ == "__main__":
     sys.path[0:1] = [str(_ROOT), str(_ROOT / "src")]
 
 from perfbench.tracereduce import Trace, top  # noqa: E402
-
-PREFIX = "engine."
-HARNESS = "host:harness"
-
-
-def engine_spans(trace_dir: str) -> list:
-    """The engine's host spans in a ``jax.profiler`` trace directory, as
-    ``[name, start_ns, duration_ns]`` in order of start."""
-    from jax.profiler import ProfileData
-
-    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
-                                     "*.xplane.pb"))
-    spans = [[e.name, e.start_ns, e.duration_ns]
-             for plane in ProfileData.from_file(path).planes
-             if plane.name.startswith("/host:")
-             for line in plane.lines for e in line.events
-             if e.name.startswith(PREFIX)]
-    return sorted(spans, key=lambda e: (e[1], -e[2]))
-
-
-def idle_by_engine_span(tr: Trace, spans: list) -> dict:
-    """Idle device seconds inside ``tr``'s window by the innermost engine
-    span (the one that started last), averaged over devices.  The engine's
-    spans nest, ``engine.gc`` included, since all run on one thread."""
-    lo, hi = tr.window()
-    # at one instant: closes before opens, and the longer span opens first
-    marks = sorted([(s, 1, -d, n) for n, s, d in spans]
-                   + [(s + d, 0, d, n) for n, s, d in spans])
-    segs, open_, prev = [], [], lo
-    for t, opens, _, name in marks + [(hi, 0, 0, None)]:
-        t = min(max(t, lo), hi)
-        if t > prev:
-            segs.append((prev, t, open_[-1] if open_ else HARNESS))
-            prev = t
-        if name is None:
-            break
-        if opens:
-            open_.append(name)
-        else:
-            open_.remove(name)
-    out: dict = {}
-    devs = tr.devices()
-    for d in devs:
-        k = 0
-        for s, e in tr.busy(d)[1]:
-            while k < len(segs) and segs[k][1] <= s:
-                k += 1
-            j = k
-            while j < len(segs) and segs[j][0] < e:
-                a, b, name = segs[j]
-                part = min(b, e) - max(a, s)
-                out[name] = out.get(name, 0.0) + part / 1e9 / len(devs)
-                j += 1
-    return out
 
 
 def traced_pass(workload: str, seed: int, seconds: float, *,
@@ -122,10 +67,10 @@ def traced_pass(workload: str, seed: int, seconds: float, *,
         serve.run_schedule(eng, inst, serve.items_for(cell, seconds, seed),
                            start, mix["ramp_s"], seconds, mix["grace_s"],
                            trace=trace)
-        tr, spans = Trace.from_dir(trace_dir), engine_spans(trace_dir)
+        tr = Trace.from_dir(trace_dir)
     obs = Observed(cfg=cell.config, peaks=None,
                    names=bench.trace_names(root), served=None, trace=tr)
-    return {"engine_idle": top(idle_by_engine_span(tr, spans), 20),
+    return {"engine_idle": top(tr.idle_by_engine_span(), 20),
             "idle_gaps": top(tr.idle_by_host(), 20),
             "step_host_ms": step_host_ms.read(obs)}
 

@@ -13,7 +13,8 @@ class Observed:
     ``calls`` are the engine calls the host made between the profiler's start
     and stop; since every step waits for its tokens, the device ran exactly
     their work in the traced window.  ``trace`` is None in a run without a
-    trace.
+    trace.  ``arch`` is the configuration's module under ``perfbench/arch/``,
+    which counts the model's operations and bytes.
     """
 
     cfg: dict
@@ -23,6 +24,7 @@ class Observed:
     trace: Optional[object] = None
     calls: Optional[object] = None
     trace_window: Optional[tuple] = None
+    arch: Optional[object] = None
 
 
 def per_call(events) -> Optional[float]:

@@ -1,8 +1,8 @@
 """Model step, decode: model operations of the tokens decoded per step
-(``flops.decode_flops`` over the slots in use and their contexts), over the
-decode program's device time per execution times the bf16 peak."""
+(the architecture's ``decode_flops`` over the slots in use and their
+contexts), over the decode program's device time per execution times the
+bf16 peak."""
 
-from perfbench import flops
 from perfbench.metrics._common import per_call
 
 
@@ -12,6 +12,6 @@ def read(obs):
     t = per_call(obs.trace.matching(obs.names["decode_program"], "modules"))
     if t is None:
         return None
-    work = sum(flops.decode_flops(obs.cfg, ctx)
+    work = sum(obs.arch.decode_flops(obs.cfg, ctx)
                for _, _, ctx in obs.calls.decode) / len(obs.calls.decode)
     return 100.0 * work / (t * obs.peaks["bf16_flops_per_s"])

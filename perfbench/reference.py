@@ -1,12 +1,12 @@
-"""Plain float32 reference of a dense decoder (Llama layout).
+"""The plain float32 reference: what every architecture's forward pass is
+built from, and the interface the correctness check calls.
 
-Written from the published description of the architecture, not from the
-program under test, whose code it does not import: token embedding, then per
-layer RMSNorm, grouped-query attention with rotary position embedding
-(rotate-half form, ``rope_theta``), a causal mask and scores scaled by
-1/sqrt(head_dim), a residual add, RMSNorm and a SwiGLU MLP with its own
-residual add; a final RMSNorm and the output head (the embedding when
-``tie_word_embeddings``).
+Written from the published description of each architecture, not from the
+program under test, whose code it does not import.  The layer stack lives in
+the configuration's module under ``perfbench/arch/``; this file keeps the
+generic parts: the matrix product in the reference's precision or the
+control's, RMSNorm, rotary position embedding, blocked causal attention,
+padding of the sequence, and the per-tensor int8 scales.
 
 It reads the configuration file and the weight tree that the benchmark made
 from the seed.  Every matrix product runs at ``Precision.HIGHEST``, since a
@@ -26,7 +26,6 @@ the correctness check:
 
 from __future__ import annotations
 
-import functools
 import math
 
 import jax
@@ -97,8 +96,10 @@ def rope(x, pos, theta):
     return x * cos + jnp.concatenate([-x2, x1], -1) * sin
 
 
-def attention(q, k, v, scale, mm):
-    """Causal grouped-query attention; q: (T, H, hd), k/v: (T, K, hd)."""
+def attention(q, k, v, scale, mm, window=None):
+    """Causal grouped-query attention; q: (T, H, hd), k/v: (T, K, hd).  With
+    ``window``, a query sees only itself and the ``window - 1`` keys before
+    it."""
     T, H, hd = q.shape
     K = k.shape[1]
     qg = q.reshape(T // Q_BLOCK, Q_BLOCK, K, H // K, hd)
@@ -108,45 +109,15 @@ def attention(q, k, v, scale, mm):
         i, qb = args
         s = mm("qkgd,tkd->kgqt", qb, k) * scale
         rows = i * Q_BLOCK + jnp.arange(Q_BLOCK)
-        s = jnp.where(cols[None, :] <= rows[:, None], s, -jnp.inf)
+        keep = cols[None, :] <= rows[:, None]
+        if window is not None:
+            keep &= cols[None, :] > rows[:, None] - window
+        s = jnp.where(keep, s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1)
         return mm("kgqt,tkd->qkgd", p, v)
 
     out = jax.lax.map(block, (jnp.arange(T // Q_BLOCK), qg))
     return out.reshape(T, H, hd)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg_items", "control"))
-def _layer(h, blocks, i, scales, *, cfg_items, control):
-    cfg = dict(cfg_items)
-    mm = Matmul(control, scales)
-    w = {path: mm.weight(path, leaf[i]) for path, leaf in blocks.items()}
-    T = h.shape[0]
-    pos = jnp.arange(T)
-    x = rms_norm(h, w["attn_norm/scale"], cfg["rms_norm_eps"])
-    q = rope(mm("td,dnk->tnk", x, w["attn/wq"]), pos, cfg["rope_theta"])
-    k = rope(mm("td,dnk->tnk", x, w["attn/wk"]), pos, cfg["rope_theta"])
-    v = mm("td,dnk->tnk", x, w["attn/wv"])
-    o = attention(q, k, v, q.shape[-1] ** -0.5, mm)
-    h = h + mm("tnk,nkd->td", o, w["attn/wo"])
-    x = rms_norm(h, w["mlp_norm/scale"], cfg["rms_norm_eps"])
-    g = mm("td,df->tf", x, w["mlp/wi_gate"])
-    u = mm("td,df->tf", x, w["mlp/wi_up"])
-    y = mm("tf,fd->td", jax.nn.silu(g) * u, w["mlp/wo"])
-    return h + y
-
-
-@functools.partial(jax.jit, static_argnames=("control",))
-def _embed(table, tokens, scales, *, control):
-    return Matmul(control, scales).weight("embed", table[tokens])
-
-
-@functools.partial(jax.jit, static_argnames=("cfg_items", "control"))
-def _head(h, rows, norm, out_w, scales, *, cfg_items, control):
-    cfg = dict(cfg_items)
-    mm = Matmul(control, scales)
-    x = rms_norm(h[rows], norm.astype(jnp.float32), cfg["rms_norm_eps"])
-    return mm("td,vd->tv", x, mm.weight("head", out_w))
 
 
 def _flat(tree, prefix=""):
@@ -160,33 +131,25 @@ def _flat(tree, prefix=""):
 
 
 class Reference:
-    """The reference over one configuration file and one weight tree."""
+    """The reference over one configuration file, one weight tree and the
+    configuration's architecture module (``perfbench/arch/``)."""
 
-    def __init__(self, cfg: dict, params: dict):
-        self.cfg = {
-            "rms_norm_eps": float(cfg["rms_norm_eps"]),
-            "rope_theta": float(cfg["rope_theta"]),
-        }
-        self.items = tuple(sorted(self.cfg.items()))
-        self.n_layers = cfg["num_hidden_layers"]
-        self.table = params["embed"]["table"]
-        self.out_w = (self.table if cfg["tie_word_embeddings"]
-                      else params["unembed"]["w"])
-        self.final_norm = params["final_norm"]["scale"]
-        self.blocks = _flat(params["blocks"])
+    def __init__(self, cfg: dict, params: dict, arch):
+        self.cfg = cfg
+        self.arch = arch
+        self.params = _flat(params)
         self._int8_scales = None
 
     def scales(self, control):
-        """Per-tensor int8 scales: max |w| / 127 over each whole tensor of
-        two or more dimensions (the stacked layer tensors, the embedding and
-        the head), as the program's quantize path takes them."""
+        """Per-tensor int8 scales by path: max |w| / 127 over each whole
+        tensor of two or more dimensions (stacked layer tensors, embedding,
+        head), as the program's quantize path takes them."""
         if control != "int8":
             return {}
         if self._int8_scales is None:
-            leaves = dict(self.blocks, embed=self.table, head=self.out_w)
             self._int8_scales = {
                 k: jnp.max(jnp.abs(v.astype(jnp.float32))) / 127.0
-                for k, v in leaves.items()}
+                for k, v in self.params.items() if v.ndim >= 2}
         return self._int8_scales
 
     def logits(self, tokens: np.ndarray, rows: np.ndarray, control=None):
@@ -196,13 +159,6 @@ class Reference:
         T = max(PAD_TO, math.ceil(n / PAD_TO) * PAD_TO)
         padded = np.zeros((T,), np.int32)
         padded[:n] = tokens
-        kw = {"cfg_items": self.items, "control": control}
-        scales = self.scales(control)
-        pick = lambda *names: {k: scales[k] for k in names if k in scales}
-        h = _embed(self.table, jnp.asarray(padded), pick("embed"),
-                   control=control)
-        layer_scales = pick(*self.blocks)
-        for i in range(self.n_layers):
-            h = _layer(h, self.blocks, i, layer_scales, **kw)
-        return _head(h, jnp.asarray(rows, jnp.int32), self.final_norm,
-                     self.out_w, pick("head"), **kw)
+        return self.arch.reference_logits(
+            self.cfg, self.params, jnp.asarray(padded),
+            jnp.asarray(rows, jnp.int32), control, self.scales(control))

@@ -17,9 +17,9 @@ limits are files found by the names in ``BENCHMARK.json`` (see
 3. offers the seeded open-loop traffic: a ramp, then the measured window of
    ``--seconds``, then load that goes on while the window's requests drain
    (``perfbench/serve.py``); set-up ends when the window opens;
-4. reads the peak device memory, frees the engine, and compares a seeded
-   sample of the served tokens with the plain float32 reference
-   (``perfbench/check.py``);
+4. reads the peak device memory, frees every array the engine holds on the
+   device but the weights, and compares a seeded sample of the served tokens
+   with the plain float32 reference (``perfbench/check.py``);
    With ``--control int8|fp8`` the control takes the program's place in that
    comparison (the tokens it puts first are judged instead of the served
    ones), which a sound limit must fail; measured runs never pass it;
@@ -40,7 +40,6 @@ import time
 PROCESS_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import gc  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import pathlib  # noqa: E402
@@ -83,15 +82,15 @@ def setup_jax() -> str:
 def build(cell, seed: int):
     """Weights from the seed and an engine over them."""
     import jax
-    from perfbench import bench, weights
+    from perfbench import weights
     from repro.compile import LoweringConfig
     from repro.serve.engine import ContinuousEngine
 
     srv = cell.config["serve"]
-    params = weights.make(cell.config, seed)
+    params = weights.make(cell.config, seed, cell.arch)
     jax.block_until_ready(params)
     eng = ContinuousEngine(
-        bench.model_config(cell.config), params,
+        cell.arch.program_config(cell.config), params,
         max_batch=srv["max_batch"], page_size=srv["page_size"],
         max_len=srv["max_len"], n_pages=srv.get("n_pages"),
         prompt_buckets=tuple(srv["prompt_buckets"]),
@@ -106,6 +105,18 @@ def memory_peak() -> int:
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
              for d in jax.local_devices()]
     return int(max(peaks))
+
+
+def free_device_state(eng, keep) -> None:
+    """Delete every device array the engine holds, whatever holds it (page
+    pools, recurrent state, staged inputs), except the leaves of ``keep``."""
+    import jax
+    from perfbench import serve
+
+    kept = {id(leaf) for leaf in jax.tree.leaves(keep)}
+    for a in serve.device_arrays(eng):
+        if id(a) not in kept:
+            a.delete()
 
 
 def run(args, *, require_device: bool = True, root=None,
@@ -182,14 +193,16 @@ def run(args, *, require_device: bool = True, root=None,
             decode=[c for c in served.calls.decode if on <= c[0] <= off])
         obs = Observed(cfg=cell.config, peaks=peaks,
                        names=bench.trace_names(root), served=served,
-                       trace=tr, calls=calls, trace_window=(on, off))
+                       trace=tr, calls=calls, trace_window=(on, off),
+                       arch=cell.arch)
         for m in cell.per_layer:
             v = bench.metric_reader(m["name"])(obs)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
         device.update(busy_s=tr.busy_s(), window_s=tr.window_s())
         breakdown = {"device_ops": top(tr.self_time_by_name()),
-                     "idle_gaps": top(tr.idle_by_host())}
+                     "idle_gaps": top(tr.idle_by_host()),
+                     "engine_idle": top(tr.idle_by_engine_span())}
     else:
         e2e = serve.end_to_end(served)
         e2e["setup_s"] = setup_s
@@ -200,12 +213,12 @@ def run(args, *, require_device: bool = True, root=None,
                           mix["check_tokens"])
     # The program's state goes before the reference runs; the weights were
     # made by the benchmark and stay.
-    eng.cache.k_pages = eng.cache.v_pages = eng._device_state = None
+    free_device_state(eng, keep=params)
     del eng, inst
-    gc.collect()
     t_ref = time.perf_counter()
     controls = (args.control,) if args.control else ()
-    got = check.compare(Reference(cell.config, params), picked, controls)
+    got = check.compare(Reference(cell.config, params, cell.arch), picked,
+                        controls)
     bench.log(f"reference over {got['requests']} requests, {got['tokens']} "
               f"served tokens, took {time.perf_counter() - t_ref:.1f} s")
     if args.control:

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+import types
 from typing import Optional
 
 import jax
@@ -103,7 +104,32 @@ def warm_up(eng, buckets, vocab: int, seed: int) -> None:
         eng.submit(r)
     while eng.queue or eng.scheduler.has_active():
         eng.step()
-    jax.block_until_ready(eng.cache.k_pages)
+    jax.block_until_ready(device_arrays(eng))
+
+
+_OPAQUE = (type, types.ModuleType, types.FunctionType, types.MethodType,
+           types.BuiltinFunctionType)
+
+
+def device_arrays(obj) -> list:
+    """The live device arrays reachable from ``obj`` through attributes and
+    containers (functions, classes and modules are not followed)."""
+    out, seen, stack = [], set(), [obj]
+    while stack:
+        o = stack.pop()
+        if id(o) in seen or isinstance(o, _OPAQUE):
+            continue
+        seen.add(id(o))
+        if isinstance(o, jax.Array):
+            if not o.is_deleted():
+                out.append(o)
+        elif isinstance(o, dict):
+            stack.extend(o.values())
+        elif isinstance(o, (list, tuple, set, frozenset)):
+            stack.extend(o)
+        elif hasattr(o, "__dict__"):
+            stack.extend(vars(o).values())
+    return out
 
 
 @dataclasses.dataclass

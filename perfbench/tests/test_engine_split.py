@@ -1,5 +1,6 @@
-"""The engine's spans in a trace (``perfbench/engine_split.py``), and the
-readers of ``metrics/step_host_ms.py`` and ``metrics/prefill_pad_share.py``:
+"""The engine's spans in a trace (``Trace.engine_spans``, read by
+``perfbench/engine_split.py`` and ``run.py``'s ``breakdown.engine_idle``),
+and the readers of ``metrics/step_host_ms.py`` and ``metrics/prefill_pad_share.py``:
 known answers on a hand-made trace, the recorded trace, which holds no
 engine span, and one traced pass of the tiny cell on the CPU."""
 
@@ -11,9 +12,9 @@ from types import SimpleNamespace
 import pytest
 
 from perfbench import bench, engine_split
-from perfbench.engine_split import HARNESS, idle_by_engine_span
 from perfbench.metrics._common import Observed
 from perfbench.tests import tiny
+from perfbench.tracereduce import NO_ENGINE_SPAN as HARNESS
 from perfbench.tracereduce import Trace
 
 DATA = pathlib.Path(__file__).resolve().parent / "data"
@@ -22,7 +23,7 @@ NAMES = {"decode_program": [["jit__decode_fn("]]}
 STEP_HOST_MS = bench.metric_reader("step_host_ms.chat")
 
 
-def handmade(spans=None):
+def handmade(spans=None, engine_spans=()):
     """Device busy 0-10, 30-40, 60-70 (two decode programs); the harness
     in ``step`` 0-50 (``decode`` 2-5) and ``stamp`` 50-70."""
     return Trace(
@@ -31,7 +32,8 @@ def handmade(spans=None):
         modules={DEV: [["jit__decode_fn(2)", 0, 10],
                        ["jit__decode_fn(2)", 30, 10]]},
         spans=spans if spans is not None else [
-            ["step", 0, 50], ["decode", 2, 3], ["stamp", 50, 20]])
+            ["step", 0, 50], ["decode", 2, 3], ["stamp", 50, 20]],
+        engine_spans=list(engine_spans))
 
 
 # The engine's step 1-48 holding decode 2-6, fetch 8-35 and bookkeep 40-45,
@@ -42,14 +44,16 @@ ENGINE_SPANS = [["engine.step", 1, 47], ["engine.decode", 2, 4],
 
 
 def test_idle_by_innermost_engine_span():
-    assert idle_by_engine_span(handmade(), ENGINE_SPANS) == pytest.approx(
+    tr = handmade(engine_spans=ENGINE_SPANS)
+    assert tr.idle_by_engine_span() == pytest.approx(
         {"engine.fetch": 20e-9, "engine.bookkeep": 4e-9, "engine.gc": 11e-9,
          HARNESS: 5e-9})
 
 
 def test_span_opened_at_the_same_instant_as_its_parent_is_innermost():
-    spans = [["engine.step", 10, 40], ["engine.fetch", 10, 25]]
-    assert idle_by_engine_span(handmade(), spans) == pytest.approx(
+    tr = handmade(engine_spans=[["engine.step", 10, 40],
+                                ["engine.fetch", 10, 25]])
+    assert tr.idle_by_engine_span() == pytest.approx(
         {"engine.fetch": 20e-9, "engine.step": 10e-9, HARNESS: 10e-9})
 
 
@@ -86,7 +90,8 @@ def test_recorded_trace_without_engine_spans():
     harness's step and the spans nested in it, per decode run."""
     tr, _ = _recorded()
     idle = tr.idle_by_host()
-    assert idle_by_engine_span(tr, []) == pytest.approx(
+    assert tr.engine_spans == []
+    assert tr.idle_by_engine_span() == pytest.approx(
         {HARNESS: sum(idle.values())})
     names = bench.trace_names()
     runs = len(tr.matching(names["decode_program"], "modules"))
@@ -145,6 +150,21 @@ def test_traced_pass_splits_idle_by_engine_span(tmp_path):
     assert out["engine_idle"] == [] and out["step_host_ms"] is None
 
 
+def test_traced_run_breakdown_has_engine_idle(tmp_path):
+    from perfbench import run
+
+    root = tiny.make_root(tmp_path)
+    res = run.run(run.parse(["--workload", "tiny.test", "--seed",
+                             str(2 ** 31 + 17), "--seconds", "2",
+                             "--trace", "1"]),
+                  require_device=False, root=root)
+    assert set(res["breakdown"]) == {"device_ops", "idle_gaps",
+                                     "engine_idle"}
+    # the CPU trace has no device plane: nothing is idle
+    assert res["breakdown"]["engine_idle"] == []
+    assert res["correct"] is True
+
+
 def test_engine_spans_read_back(tmp_path):
     import jax
 
@@ -154,7 +174,9 @@ def test_engine_spans_read_back(tmp_path):
                 pass
         with jax.profiler.TraceAnnotation("step"):
             pass
-    spans = engine_split.engine_spans(str(tmp_path))
+    tr = Trace.from_dir(str(tmp_path))
+    spans = tr.engine_spans
+    assert [s[0] for s in tr.spans] == ["step"]
     assert [s[0] for s in spans] == ["engine.step", "engine.fetch"]
     assert spans[0][1] <= spans[1][1]
     assert spans[1][1] + spans[1][2] <= spans[0][1] + spans[0][2]

@@ -12,7 +12,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from perfbench import bench, weights
+from perfbench import weights
+from perfbench.arch import llama
 from perfbench.reference import Reference
 from perfbench.tests import tiny
 from repro.compile import LoweringConfig
@@ -31,7 +32,7 @@ def rel(got, want):
 def engine(cfg, params):
     srv = cfg["serve"]
     return ContinuousEngine(
-        bench.model_config(cfg), params, max_batch=srv["max_batch"],
+        llama.program_config(cfg), params, max_batch=srv["max_batch"],
         page_size=srv["page_size"], max_len=srv["max_len"],
         prompt_buckets=tuple(srv["prompt_buckets"]),
         lowering=LoweringConfig.from_registry("xla"))
@@ -58,8 +59,8 @@ def prefill_and_decode(eng, params, prompt, bucket, token):
 
 @pytest.mark.parametrize("n,bucket", [(20, 32), (57, 64)])
 def test_engine_agrees_with_reference(n, bucket):
-    params = weights.make(CFG, 5)
-    ref = Reference(CFG, params)
+    params = weights.make(CFG, 5, llama)
+    ref = Reference(CFG, params, llama)
     prompt = np.random.default_rng(n).integers(0, CFG["vocab_size"], n,
                                                dtype=np.int32)
     token = 7
@@ -77,8 +78,8 @@ def test_model_without_causal_mask_fails(monkeypatch):
         return jnp.ones((1, S, T or S), bool)
 
     monkeypatch.setattr(layers, "make_mask", no_mask)
-    params = weights.make(CFG, 5)
-    ref = Reference(CFG, params)
+    params = weights.make(CFG, 5, llama)
+    ref = Reference(CFG, params, llama)
     n, bucket = 20, 32
     prompt = np.random.default_rng(0).integers(0, CFG["vocab_size"], n,
                                                dtype=np.int32)
@@ -91,13 +92,13 @@ def test_model_without_causal_mask_fails(monkeypatch):
 
 def test_weights_repeat_for_a_seed_past_32_bits():
     seed = 2 ** 33 + 5
-    a = weights.make(CFG, seed)["final_norm"]["scale"]
-    b = weights.make(CFG, seed)["final_norm"]["scale"]
-    c = weights.make(CFG, seed + 2 ** 32)["final_norm"]["scale"]
+    a = weights.make(CFG, seed, llama)["final_norm"]["scale"]
+    b = weights.make(CFG, seed, llama)["final_norm"]["scale"]
+    c = weights.make(CFG, seed + 2 ** 32, llama)["final_norm"]["scale"]
     assert np.array_equal(a, b) and not np.array_equal(a, c)
 
 
 def test_config_maps_to_program():
-    mc = bench.model_config(CFG)
+    mc = llama.program_config(CFG)
     assert dataclasses.asdict(mc)["tie_embeddings"] is True
     assert mc.resolved_head_dim() == CFG["head_dim"]

@@ -7,6 +7,7 @@ import pathlib
 import pytest
 
 from perfbench import bench
+from perfbench.arch import llama
 from perfbench.metrics._common import Observed
 from perfbench.tracereduce import Trace, top
 
@@ -82,7 +83,7 @@ def test_recorded_trace_reduction():
     obs = Observed(cfg=json.loads((DATA / "small_config.json").read_text()),
                    peaks=bench.load_peaks("TPU v5 lite"), names=names,
                    served=None, trace=tr, calls=_calls(calls),
-                   trace_window=(0.0, 1.0))
+                   trace_window=(0.0, 1.0), arch=llama)
     for metric in ("decode_step_ms", "decode_mfu", "prefill_mfu",
                    "flash_roofline", "device_idle_share"):
         v = bench.metric_reader(metric)(obs)

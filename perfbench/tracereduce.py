@@ -10,8 +10,10 @@ A scan's ``while`` op spans the ops of its body.  A Pallas kernel is a
 its own, so op names are kept in a compact form that still tells kernels
 apart: the instruction's name, and for a custom call its target and operand
 dtypes (``%closed_call.23 tpu_custom_call (bf16,bf16,bf16,s32)``).  Host
-spans are the benchmark's own ``TraceAnnotation`` events on the host plane.
-All times are nanoseconds on the profiler's one clock.
+spans are the benchmark's own ``TraceAnnotation`` events on the host plane;
+engine spans are those the program writes there, named ``engine.*``
+(``repro.serve.telemetry``).  All times are nanoseconds on the profiler's
+one clock.
 """
 
 from __future__ import annotations
@@ -25,6 +27,9 @@ import re
 #: Host spans the harness writes (see ``perfbench/serve.py``), innermost first
 #: when several cover the same instant.
 HOST_SPANS = ("prefill", "decode", "admit", "stamp", "step", "idle_wait")
+#: Prefix of the engine's own spans, and the name of idle time under none.
+ENGINE_PREFIX = "engine."
+NO_ENGINE_SPAN = "host:harness"
 _DEVICE_PLANE = re.compile(r"^/device:[A-Z]+:\d+$")
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 
@@ -43,17 +48,19 @@ def compact(hlo: str) -> str:
 @dataclasses.dataclass
 class Trace:
     """Events of one traced window: ``ops`` and ``modules`` per device, as
-    ``[name, start_ns, duration_ns]``; ``spans`` of the host likewise."""
+    ``[name, start_ns, duration_ns]``; the harness's ``spans`` and the
+    program's ``engine_spans`` on the host likewise."""
 
     ops: dict
     modules: dict
     spans: list
+    engine_spans: list = dataclasses.field(default_factory=list)
 
     @classmethod
     def from_xplane(cls, path: str) -> "Trace":
         from jax.profiler import ProfileData
 
-        ops, modules, spans = {}, {}, []
+        ops, modules, spans, engine = {}, {}, [], []
         for plane in ProfileData.from_file(path).planes:
             if _DEVICE_PLANE.match(plane.name):
                 for line in plane.lines:
@@ -67,10 +74,13 @@ class Trace:
                             for e in line.events)
             elif plane.name.startswith("/host:"):
                 for line in plane.lines:
-                    spans.extend([e.name, e.start_ns, e.duration_ns]
-                                 for e in line.events
-                                 if e.name in HOST_SPANS)
-        return cls(ops, modules, sorted(spans, key=lambda e: e[1]))
+                    for e in line.events:
+                        if e.name in HOST_SPANS:
+                            spans.append([e.name, e.start_ns, e.duration_ns])
+                        elif e.name.startswith(ENGINE_PREFIX):
+                            engine.append([e.name, e.start_ns, e.duration_ns])
+        return cls(ops, modules, sorted(spans, key=lambda e: e[1]),
+                   sorted(engine, key=lambda e: (e[1], -e[2])))
 
     @classmethod
     def from_dir(cls, trace_dir: str) -> "Trace":
@@ -87,7 +97,8 @@ class Trace:
 
     @classmethod
     def from_json(cls, d: dict) -> "Trace":
-        return cls(d["ops"], d["modules"], d["spans"])
+        return cls(d["ops"], d["modules"], d["spans"],
+                   d.get("engine_spans", []))
 
     def save(self, path: str) -> None:
         with open(path, "w") as f:
@@ -184,11 +195,42 @@ class Trace:
             prev = t
         return out
 
+    def engine_segments(self) -> list:
+        """The window cut into ``(start, end, span)`` pieces, each labelled
+        with the innermost engine span (the one that opened last) covering
+        it, ``NO_ENGINE_SPAN`` where none does.  The engine's spans nest,
+        ``engine.gc`` included, since all run on one thread."""
+        lo, hi = self.window()
+        spans = self.engine_spans
+        # at one instant: closes before opens, and the longer span opens first
+        marks = sorted([(s, 1, -d, n) for n, s, d in spans]
+                       + [(s + d, 0, d, n) for n, s, d in spans])
+        segs, open_, prev = [], [], lo
+        for t, opens, _, name in marks + [(hi, 0, 0, None)]:
+            t = min(max(t, lo), hi)
+            if t > prev:
+                segs.append((prev, t, open_[-1] if open_ else NO_ENGINE_SPAN))
+                prev = t
+            if name is None:
+                break
+            if opens:
+                open_.append(name)
+            else:
+                open_.remove(name)
+        return segs
+
     def idle_by_host(self) -> dict:
-        """Idle device seconds by what the host was doing during each part
-        of each gap, averaged over devices."""
+        """Idle device seconds by what the harness was doing during each
+        part of each gap, averaged over devices."""
+        return self._idle_by(self.host_segments())
+
+    def idle_by_engine_span(self) -> dict:
+        """Idle device seconds by the innermost engine span, averaged over
+        devices."""
+        return self._idle_by(self.engine_segments())
+
+    def _idle_by(self, segs: list) -> dict:
         out: dict = {}
-        segs = self.host_segments()
         devs = self.devices()
         for d in devs:
             k = 0
