@@ -7,8 +7,9 @@ dispatch → Pallas kernels, with the lowering the platform picks (compiled
 Pallas on a TPU).  Then it checks that
 
 * every request got its ``max_new_tokens`` and no KV page leaked;
-* the dispatcher lowered ``attention`` and ``rmsnorm`` to ISAX kernels at
-  every prefill bucket, and the compiled prefill holds a ``tpu_custom_call``;
+* the dispatcher lowered causal prefill attention (``attention_causal``)
+  and ``rmsnorm`` to ISAX kernels at every prefill bucket, and the compiled
+  prefill holds a ``tpu_custom_call``;
 * prefill logits at each bucket, and the logits of the first paged decode
   step, agree with an engine on the XLA reference lowering, run on the same
   parameters on the same chip.
@@ -136,12 +137,13 @@ def check_requests(eng: ContinuousEngine, reqs) -> None:
 
 
 def dispatch_lines(eng: ContinuousEngine, plan: Plan) -> list[str]:
-    """Check that attention and RMSNorm lowered to ISAX kernels at every
-    prefill bucket; returns one line per compile record of this engine."""
+    """Check that causal prefill attention and RMSNorm lowered to ISAX
+    kernels at every prefill bucket; returns one line per compile record of
+    this engine."""
     recs = [r for r in eng.lowering.dispatcher.records.values()
             if r.key.backend == eng.lowering.backend]
     for b in plan.buckets:
-        for op, rows in (("attention", lambda s: s[1]),
+        for op, rows in (("attention_causal", lambda s: s[1]),
                          ("rmsnorm", lambda s: s[0])):
             got = [r for r in recs if r.key.op == op and rows(r.key.shape) == b]
             if not got or any(r.impl != "isax" for r in got):

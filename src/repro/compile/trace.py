@@ -57,7 +57,8 @@ class OpKey:
     """Compile-cache key: one persistent entry per (op, shape, dtype, backend).
 
     Shape conventions (built-in domains):
-      attention / attention_decode / attention_paged: (B, S, H, K, T, hd)
+      attention / attention_decode / attention_paged / attention_causal:
+                   (B, S, H, K, T, hd)
       rmsnorm:     (rows, d)
       matmul:      (rows, d_in, d_out)
       int8_matmul: (rows, d_in, d_out)

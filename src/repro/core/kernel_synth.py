@@ -32,6 +32,7 @@ from repro.core.interface_model import (
     tpu_interfaces,
 )
 from repro.core.synthesis import synthesize
+from repro.core.tiling import causal_tiles
 from repro.roofline.analysis import pipeline_speedup
 
 # MXU does a 128x128x128 bf16 matmul-accumulate per ~1 cycle equivalent:
@@ -256,12 +257,17 @@ def choose_flash_blocks(
     seq_q: int, seq_k: int, head_dim: int,
     dtype_bytes: int = 2,
     vmem_budget: int = TPU_VMEM_BUDGET,
+    causal: bool = False,
 ) -> KernelSchedule:
     """Pick (block_q, block_k) + buffering for the flash-attention ISAX.
 
     Working set per step: Q tile (persistent across the kv loop — "warm"),
-    K/V tiles (streamed — "cold"), running stats, O accumulator.
+    K/V tiles (streamed — "cold"), running stats, O accumulator.  A
+    ``causal`` call is costed by ``_choose_causal_flash_blocks``.
     """
+    if causal:
+        return _choose_causal_flash_blocks(seq_q, seq_k, head_dim,
+                                           dtype_bytes, vmem_budget)
     best: KernelSchedule | None = None
     hd = max(head_dim, MXU_DIM)  # lane-padded head dim
     itfc = tpu_interfaces()["hbm_vmem"]
@@ -305,6 +311,88 @@ def choose_flash_blocks(
                         },
                         pipelined=cost.pipelined,
                         est_serial_cycles=cost.serial_total * q_steps,
+                        pipeline_gain=cost.gain)
+    assert best is not None, "no feasible flash tiling"
+    return best
+
+
+#: Fixed cost of one grid step of the causal flash kernel, computed or
+#: skipped: the step's bookkeeping and its DMA issue and wait.
+FLASH_GRID_STEP_CYCLES = 529
+#: Per q row of a computed step: the (bq, 1) statistic columns (max,
+#: denominator, rescale factor), their lane reductions over the tile, and
+#: the accumulator's rescale.  Paid once per row per step whatever the kv
+#: tile's width, it is what makes narrow kv tiles slow.
+FLASH_ROW_CYCLES = 2.15
+#: VPU operations per score element of a tile the diagonal crosses: the
+#: iota mask and the two selects.
+FLASH_MASK_VPU_OPS = 10
+#: f32 copies of a (bq, bk) score tile live at once: the scores, their
+#: exponentials, and p in the PV dot's dtype.
+FLASH_SCORE_COPIES = 3
+# The three cycle counts are fitted, with the MXU term at its peak rate, to
+# the causal kernel's times on one TPU v5e over 21 (bq, bk) tilings at
+# S = 1024, 2048 and 4096 (PERF.md section 5).
+
+
+def _choose_causal_flash_blocks(seq_q: int, seq_k: int, head_dim: int,
+                                dtype_bytes: int,
+                                vmem_budget: int) -> KernelSchedule:
+    """Tiles for the causal flash kernel, costed over the tiles it runs.
+
+    Tiles wholly below the diagonal cost the MXU work and the per-row
+    statistics, tiles the diagonal crosses add the mask, tiles above it are
+    skipped but still pay their grid step, and each q tile pays one
+    pipeline-fill DMA.  The q tile is warm and the K/V tiles cold, as in
+    the dense schedule.
+    """
+    best: KernelSchedule | None = None
+    hd = max(head_dim, MXU_DIM)  # lane-padded head dim
+    itfc = tpu_interfaces()["hbm_vmem"]
+    caps = (128, 256, 512, 1024, 2048)
+    for bq in _candidate_tiles(seq_q, 8, caps):
+        for bk in _candidate_tiles(seq_k, MXU_DIM, caps):
+            below, crossing, total = causal_tiles(seq_q, seq_k, bq, bk)
+            ran = below + crossing
+            for buf in PIPELINE_DEPTHS:
+                q_b = bq * hd * dtype_bytes
+                kv_b = 2 * bk * hd * dtype_bytes
+                n_bufs = max(buf, BASELINE_OVERLAP)
+                vmem = (q_b + n_bufs * kv_b + bq * hd * 4
+                        + FLASH_SCORE_COPIES * bq * bk * 4 + bq * 4 * 2)
+                if vmem > vmem_budget:
+                    continue
+                dma = _dma_cycles("flash_step", [("kv_tile", kv_b, "load")])
+                flops = 4 * bq * bk * hd
+                compute = flops / _MXU_FLOPS_PER_CYCLE + FLASH_ROW_CYCLES * bq
+                cost = _pipeline_cost(compute, dma, buf, ran, flops, kv_b,
+                                      itfc)
+                if buf > 1 and not cost.pipelined:
+                    continue
+                q_steps = seq_q // bq
+                fixed = (crossing * FLASH_MASK_VPU_OPS * bq * bk
+                         / _VPU_FLOPS_PER_CYCLE
+                         + total * FLASH_GRID_STEP_CYCLES + q_steps * dma)
+                total_cycles = cost.step * ran + fixed
+                if best is None or total_cycles < best.est_total_cycles:
+                    best = KernelSchedule(
+                        name="flash_attention",
+                        block_shapes={"q": (bq, head_dim),
+                                      "kv": (bk, head_dim)},
+                        buffering=buf,
+                        est_step_cycles=cost.step,
+                        est_total_cycles=total_cycles,
+                        vmem_bytes=vmem,
+                        decisions={
+                            "bound": "compute"
+                                     if cost.step <= compute * (1 + 1e-9)
+                                     else "memory",
+                            "tiles_run": f"{ran}/{total}",
+                            "q_hint": "warm", "kv_hint": "cold",
+                            "pipeline": _pipe_note(cost, buf),
+                        },
+                        pipelined=cost.pipelined,
+                        est_serial_cycles=cost.serial_total - dma + fixed,
                         pipeline_gain=cost.gain)
     assert best is not None, "no feasible flash tiling"
     return best

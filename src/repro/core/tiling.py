@@ -56,3 +56,26 @@ def dtype_itemsize(dtype: str) -> int:
         return np.dtype(dtype).itemsize
     except TypeError:
         return 2 if dtype.endswith("16") else 4
+
+
+def last_causal_tile(qi, block_q: int, block_k: int, offset):
+    """Index of the last kv tile that q tile ``qi`` sees under the causal
+    mask ``col <= row + offset`` (on ints, and on traced scalars inside a
+    kernel or an index map)."""
+    return ((qi + 1) * block_q - 1 + offset) // block_k
+
+
+def causal_tiles(S: int, T: int, block_q: int, block_k: int):
+    """``(below, crossing, total)`` (q, kv) tile pairs of one head block of
+    a causal attention call, offset ``T − S``: tiles wholly at or below the
+    diagonal, tiles the diagonal crosses, and all of them.  The causal
+    flash kernel computes the first two kinds (masking only the second)
+    and skips the rest."""
+    nq, nk, offset = S // block_q, T // block_k, T - S
+    below = crossing = 0
+    for qi in range(nq):
+        first = qi * block_q + offset       # shifted row of the tile's top
+        n = min(nk, last_causal_tile(qi, block_q, block_k, offset) + 1)
+        full = min(n, max(0, (first + 1) // block_k))
+        below, crossing = below + full, crossing + n - full
+    return below, crossing, nq * nk

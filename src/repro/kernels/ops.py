@@ -57,13 +57,16 @@ _down_pow2 = down_pow2
 
 
 @functools.lru_cache(maxsize=None)
-def flash_tiles(S: int, T: int, H: int, K: int, hd: int, dtype_bytes: int):
+def flash_tiles(S: int, T: int, H: int, K: int, hd: int, dtype_bytes: int,
+                causal: bool = False):
     """``(schedule, (bq, bk))`` for one attention shape; the tiles are None
-    when GQA heads do not fold or the blocks break the TPU block rule."""
-    sched = choose_flash_blocks(S, T, hd, dtype_bytes)
+    when GQA heads do not fold or the blocks break the TPU block rule.  A
+    causal call is costed over the tiles at or below the diagonal only."""
+    sched = choose_flash_blocks(S, T, hd, dtype_bytes, causal=causal)
     bq = down_pow2(S, sched.block("q")[0])
     bk = down_pow2(T, sched.block("kv")[0])
-    ok = H % K == 0 and tpu_block_ok((bq, bk), (S, T))
+    ok = (H % K == 0 and tpu_block_ok((bq, bk), (S, T))
+          and (T >= S or not causal))
     return sched, ((bq, bk) if ok else None)
 
 
@@ -102,26 +105,29 @@ def _untileable(op: str, shapes) -> ValueError:
 
 
 def flash_attention_gqa(q, k, v, mask, *, sm_scale: float,
-                        interpret: bool = False,
+                        causal: bool = False, interpret: bool = False,
                         pipelined: bool | None = None):
-    """Drop-in for ``layers.sdpa``: synthesis-chosen tiles.  ``pipelined``
-    routes K/V streaming through the burst-DMA pipeline (None = the
-    synthesized cost-model decision)."""
+    """Drop-in for ``layers.sdpa``: synthesis-chosen tiles.  ``causal``
+    says ``mask`` is ``make_mask("causal")``: the kernel then builds it
+    from tile indices and skips the tiles above the diagonal.
+    ``pipelined`` routes K/V streaming through the burst-DMA pipeline (None
+    = the synthesized cost-model decision)."""
     B, S, H, hd = q.shape
     T = k.shape[1]
-    sched, tiles = flash_tiles(S, T, H, k.shape[2], hd, q.dtype.itemsize)
+    sched, tiles = flash_tiles(S, T, H, k.shape[2], hd, q.dtype.itemsize,
+                               causal)
     if tiles is None:
         if interpret:
             return ref.flash_attention_ref(q, k, v, mask, sm_scale=sm_scale)
         raise _untileable("flash_attention", (q.shape, k.shape))
     bq, bk = tiles
     mask = jnp.broadcast_to(mask, (mask.shape[0], S, T))
+    kw = dict(sm_scale=sm_scale, block_q=bq, block_k=bk, causal=causal,
+              interpret=interpret)
     if use_pipeline(sched, pipelined, T // bk):
-        return _flash_pipe(q, k, v, mask, sm_scale=sm_scale, block_q=bq,
-                           block_k=bk, depth=max(2, sched.buffering),
-                           interpret=interpret)
-    return _flash(q, k, v, mask, sm_scale=sm_scale, block_q=bq, block_k=bk,
-                  interpret=interpret)
+        return _flash_pipe(q, k, v, mask, depth=max(2, sched.buffering),
+                           **kw)
+    return _flash(q, k, v, mask, **kw)
 
 
 def int8_matmul(x, wq, scale, *, interpret: bool = False,

@@ -45,11 +45,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.tiling import last_causal_tile
 from repro.kernels.flash_attention import (
     _finalize_flash_output,
     _init_flash_scratch,
     _online_softmax_update,
     _tile_specs,
+    _tile_update,
+    flash_operands,
     flash_scratch,
     heads_major,
 )
@@ -83,7 +86,10 @@ class BurstPipeline:
         pipeline fill or a traced scalar), shaped like one slot of
         ``buf_ref`` — a VMEM scratch of shape ``(depth, *tile_shape)``.
     sem : DMA semaphore array of shape ``(len(streams), depth)``.
-    n_steps : static trip count of the streamed (sequential) grid dim.
+    n_steps : trip count of the streamed (sequential) grid dim: static, or
+        a traced scalar when a sweep stops early (the causal flash kernel's
+        last tile under the diagonal); steps from ``n_steps`` on must not
+        call ``stream_step``.
     depth : static buffer depth ≥ 2.
     """
 
@@ -115,8 +121,8 @@ class BurstPipeline:
         """
         @pl.when(step == 0)
         def _fill():
-            for d in range(min(self.depth - 1, self.n_steps)):
-                self._start_all(d)
+            for d in range(self.depth - 1):
+                pl.when(d < self.n_steps)(functools.partial(self._start_all, d))
 
         nxt = step + self.depth - 1
         @pl.when(nxt < self.n_steps)
@@ -132,12 +138,18 @@ class BurstPipeline:
 # Flash attention (K/V tiles streamed)
 # ---------------------------------------------------------------------------
 
-def _flash_pipelined_kernel(q_ref, k_hbm, v_hbm, mask_ref, o_ref,
+def _flash_pipelined_kernel(q_ref, k_hbm, v_hbm, aux_ref, o_ref,
                             k_buf, v_buf, sem, m_scr, l_scr, acc_scr,
                             *, sm_scale: float, n_kv: int, block_k: int,
-                            depth: int, n_groups: int):
-    b, h, ki = pl.program_id(0), pl.program_id(1), pl.program_id(3)
+                            depth: int, n_groups: int, causal: bool):
+    b, h = pl.program_id(0), pl.program_id(1)
+    qi, ki = pl.program_id(2), pl.program_id(3)
+    bq = q_ref.shape[2]
     kvh = h // n_groups
+    # A causal sweep streams only the tiles up to the diagonal.
+    n_steps = (jnp.minimum(n_kv, last_causal_tile(qi, bq, block_k,
+                                                  aux_ref[0]) + 1)
+               if causal else n_kv)
     pipe = BurstPipeline(
         streams=(
             (lambda t: k_hbm.at[b, kvh, pl.ds(t * block_k, block_k), :],
@@ -145,18 +157,21 @@ def _flash_pipelined_kernel(q_ref, k_hbm, v_hbm, mask_ref, o_ref,
             (lambda t: v_hbm.at[b, kvh, pl.ds(t * block_k, block_k), :],
              v_buf),
         ),
-        sem=sem, n_steps=n_kv, depth=depth)
+        sem=sem, n_steps=n_steps, depth=depth)
 
     @pl.when(ki == 0)
     def _init():
         _init_flash_scratch(m_scr, l_scr, acc_scr)
 
-    slot = pipe.stream_step(ki)
-    _online_softmax_update(
-        q_ref[0, 0].astype(jnp.float32),            # (bq, hd)
-        k_buf[slot].astype(jnp.float32),            # (bk, hd)
-        v_buf[slot].astype(jnp.float32),            # (bk, hd)
-        mask_ref[0], sm_scale, m_scr, l_scr, acc_scr)
+    @pl.when(ki < n_steps)
+    def _step():
+        slot = pipe.stream_step(ki)
+
+        def update(mask):
+            _online_softmax_update(q_ref[0, 0], k_buf[slot], v_buf[slot],
+                                   mask, sm_scale, m_scr, l_scr, acc_scr)
+
+        _tile_update(qi, ki, aux_ref, causal, bq, block_k, update)
 
     @pl.when(ki == n_kv - 1)
     def _finalize():
@@ -165,15 +180,17 @@ def _flash_pipelined_kernel(q_ref, k_hbm, v_hbm, mask_ref, o_ref,
 
 def flash_attention_pipelined(q, k, v, mask, *, sm_scale: float,
                               block_q: int = 128, block_k: int = 128,
+                              causal: bool = False,
                               depth: int = DEFAULT_DEPTH,
                               interpret: bool = False):
     """Burst-DMA flash attention: K/V tiles streamed HBM→VMEM explicitly.
 
     Same contract as ``flash_attention.flash_attention`` — q (B,S,H,hd),
-    k/v (B,T,K,hd), mask (1|B,S,T) bool → (B,S,H,hd), tiled over the same
-    heads-major view — but the K/V operands bypass BlockSpec staging and
-    flow through a ``depth``-deep rotating buffer driven by
-    ``BurstPipeline``.
+    k/v (B,T,K,hd), mask (1|B,S,T) bool, ``causal`` → (B,S,H,hd), tiled
+    over the same heads-major view — but the K/V operands bypass BlockSpec
+    staging and flow through a ``depth``-deep rotating buffer driven by
+    ``BurstPipeline``.  A causal sweep streams only the tiles at or below
+    the diagonal.
 
     Mosaic only slices an HBM ref whose minor dim is a multiple of 128
     lanes, so a head dim below that is zero-padded up to it (zero lanes add
@@ -186,6 +203,7 @@ def flash_attention_pipelined(q, k, v, mask, *, sm_scale: float,
     G = H // K
     bq, bk = min(block_q, S), min(block_k, T)
     assert S % bq == 0 and T % bk == 0, (S, bq, T, bk)
+    assert T >= S or not causal, (S, T)
     nq, nk = S // bq, T // bk
     lanes = -(-hd // 128) * 128
     if lanes != hd:
@@ -193,18 +211,20 @@ def flash_attention_pipelined(q, k, v, mask, *, sm_scale: float,
         q, k, v = jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
         out = flash_attention_pipelined(
             q, k, v, mask, sm_scale=sm_scale, block_q=bq, block_k=bk,
-            depth=depth, interpret=interpret)
+            causal=causal, depth=depth, interpret=interpret)
         return out[..., :hd]
-    q_spec, _, mask_spec = _tile_specs(bq, bk, hd, G, mask.shape[0])
+    q_spec, _, aux_spec = _tile_specs(bq, bk, hd, G, mask.shape[0],
+                                      causal_offset=T - S if causal else None)
     out = pl.pallas_call(
         functools.partial(_flash_pipelined_kernel, sm_scale=sm_scale,
-                          n_kv=nk, block_k=bk, depth=depth, n_groups=G),
+                          n_kv=nk, block_k=bk, depth=depth, n_groups=G,
+                          causal=causal),
         grid=(B, H, nq, nk),
         in_specs=[
             q_spec,
             pl.BlockSpec(memory_space=pltpu.ANY),   # K stays in HBM
             pl.BlockSpec(memory_space=pltpu.ANY),   # V stays in HBM
-            mask_spec,
+            aux_spec,
         ],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, S, hd), q.dtype),
@@ -215,7 +235,7 @@ def flash_attention_pipelined(q, k, v, mask, *, sm_scale: float,
             *flash_scratch(bq, hd),
         ],
         interpret=interpret,
-    )(heads_major(q), heads_major(k), heads_major(v), mask)
+    )(*flash_operands(q, k, v, mask, causal))
     return heads_major(out)
 
 

@@ -17,7 +17,7 @@ from repro.configs.registry import get_config
 from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.engine import ContinuousEngine, ServeEngine
 from repro.serve.scheduler import make_poisson_workload
-from repro.serve.telemetry import pad_share
+from repro.serve.telemetry import flash_tile_share, pad_share
 
 
 def main():
@@ -84,6 +84,7 @@ def main():
               f"{stats.decode_steps} decode steps)")
         print(f"engine: admissions={len(reqs)} "
               f"prefill_padding={100 * pad_share(reqs):.1f}% "
+              f"flash_tiles_run={100 * flash_tile_share(reqs, cfg):.1f}% "
               f"decode_steps={eng.decode_steps}")
         return
 

@@ -247,20 +247,24 @@ def _sdpa_chunked(q, k, v, mask, head_dim: int, chunk: int = 1024):
 
 
 def sdpa(q, k, v, mask, head_dim: int, lowering: LoweringConfig,
-         kind: str = "attention"):
+         kind: str = "attention", causal: bool = False):
     """Dispatch-routed scaled-dot-product attention (public: the enc-dec
     family calls it for cross attention).
 
     The compile cache decides the implementation per (kind, shape, dtype,
     backend); the ISAX kernel entry point is pre-resolved in the record (no
-    per-forward import).
+    per-forward import).  ``causal`` says that ``mask`` is
+    ``make_mask("causal")``: the op is then ``attention_causal``, whose
+    kernel builds the mask from tile indices and skips the tiles above the
+    diagonal; the XLA lowerings read ``mask`` either way.
     """
     B, S, H, hd = q.shape
     T, K = k.shape[1], k.shape[2]
-    rec = lowering.lower(kind, (B, S, H, K, T, hd), q.dtype)
+    rec = lowering.lower("attention_causal" if causal else kind,
+                         (B, S, H, K, T, hd), q.dtype)
     if rec.impl == "isax":
         return rec.kernel_fn(q, k, v, mask, sm_scale=head_dim ** -0.5,
-                             interpret=lowering.interpret)
+                             causal=causal, interpret=lowering.interpret)
     if rec.impl == "chunked":
         return _sdpa_chunked(q, k, v, mask, head_dim)
     return _sdpa_xla(q, k, v, mask, head_dim)
@@ -270,12 +274,14 @@ _sdpa = sdpa  # back-compat alias (one release): use layers.sdpa
 
 
 def attention(params, x, cfg: ModelConfig, mask, positions,
-              lowering: Optional[LoweringConfig] = None):
-    """Full-sequence attention (train/prefill).  Returns (out, (k, v))."""
+              lowering: Optional[LoweringConfig] = None,
+              causal: bool = False):
+    """Full-sequence attention (train/prefill).  ``causal``: ``mask`` is
+    ``make_mask("causal")`` (see ``sdpa``).  Returns (out, (k, v))."""
     lw = lowering or default_lowering()
     hd = cfg.resolved_head_dim()
     q, k, v = _qkv(params, x, cfg, positions)
-    out = sdpa(q, k, v, mask, hd, lw, kind="attention")
+    out = sdpa(q, k, v, mask, hd, lw, kind="attention", causal=causal)
     cd = dtype_of(cfg.compute_dtype)
     return jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(cd)), (k, v)
 

@@ -86,12 +86,14 @@ def param_axes(cfg: ModelConfig) -> dict:
 # Forward
 # ---------------------------------------------------------------------------
 
-def block_fwd(cfg: ModelConfig, x, bp, mask, positions, lowering=None):
+def block_fwd(cfg: ModelConfig, x, bp, mask, positions, lowering=None,
+              causal: bool = False):
     x = L.shard_act(x, "btd")
     a, kv = L.attention(bp["attn"],
                         L.rmsnorm(bp["attn_norm"], x, cfg.norm_eps,
                                   lowering=lowering),
-                        cfg, mask, positions, lowering=lowering)
+                        cfg, mask, positions, lowering=lowering,
+                        causal=causal)
     x = x + a
     if _is_moe(cfg):
         y, aux = moe_mlp(bp["moe"],
@@ -111,13 +113,15 @@ _block_fwd = block_fwd  # back-compat alias (one release): use block_fwd
 
 def backbone(params, x, cfg: ModelConfig, mask, positions,
              collect_kv: bool = False,
-             lowering: Optional[LoweringConfig] = None):
-    """Scan over stacked blocks.  Returns (hidden, aux, kv_stack|None)."""
+             lowering: Optional[LoweringConfig] = None,
+             causal: bool = False):
+    """Scan over stacked blocks; ``causal``: ``mask`` is
+    ``make_mask("causal")``.  Returns (hidden, aux, kv_stack|None)."""
     lw = lowering or default_lowering()
 
     def body(carry, bp):
         h, aux = carry
-        h2, a, kv = block_fwd(cfg, h, bp, mask, positions, lw)
+        h2, a, kv = block_fwd(cfg, h, bp, mask, positions, lw, causal)
         ys = kv if collect_kv else None
         return (h2, aux + a), ys
 
@@ -151,7 +155,8 @@ def loss(params, batch, cfg: ModelConfig, aux_weight: float = 0.01,
     mask = L.make_mask(mask_kind, S, n_prefix=cfg.n_prefix_tokens
                        if cfg.family == "vlm" else 0)
     positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-    h, aux, _ = backbone(params, x, cfg, mask, positions, lowering=lowering)
+    h, aux, _ = backbone(params, x, cfg, mask, positions, lowering=lowering,
+                         causal=mask_kind == "causal")
     logits = L.unembed(_unembed_table(params, cfg), h, cfg,
                        lowering=lowering)
     logits = L.shard_act(logits, "btv")
@@ -170,7 +175,7 @@ def prefill(params, batch, cfg: ModelConfig, pad_to: Optional[int] = None,
                        if cfg.family == "vlm" else 0)
     positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
     h, _, kv = backbone(params, x, cfg, mask, positions, collect_kv=True,
-                        lowering=lowering)
+                        lowering=lowering, causal=mask_kind == "causal")
     k_stack, v_stack = kv  # (L, B, S, K, hd)
     if pad_to and pad_to > S:
         pad = [(0, 0), (0, 0), (0, pad_to - S), (0, 0), (0, 0)]
@@ -196,7 +201,7 @@ def prefill_at(params, batch, length, cfg: ModelConfig,
     mask = L.make_mask("causal", S)
     positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
     h, _, kv = backbone(params, x, cfg, mask, positions, collect_kv=True,
-                        lowering=lowering)
+                        lowering=lowering, causal=True)
     k_stack, v_stack = kv
     h_last = jax.lax.dynamic_slice_in_dim(h, length - 1, 1, axis=1)
     logits = L.unembed(_unembed_table(params, cfg), h_last, cfg,

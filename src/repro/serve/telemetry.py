@@ -1,4 +1,5 @@
-"""Spans of the serving engine, and the prefill padding of its requests.
+"""Spans of the serving engine, and the prefill padding and flash tile share
+of its requests.
 
 Spans are ``jax.profiler`` trace annotations: they are written into the
 profiler's own trace, on the clock of the device's ops, and only while a
@@ -16,6 +17,10 @@ from __future__ import annotations
 import gc
 
 import jax
+import jax.numpy as jnp
+
+from repro.core.tiling import causal_tiles
+from repro.kernels.ops import flash_tiles
 
 
 def span(name: str, **args) -> jax.profiler.TraceAnnotation:
@@ -32,6 +37,26 @@ def pad_share(requests) -> float:
     if not padded:
         return 0.0
     return 1.0 - sum(r.prompt_len for r in admitted) / padded
+
+
+def flash_tile_share(requests, cfg) -> float:
+    """Share of the causal flash kernel's grid tiles that ran compute in the
+    prefills of ``requests`` (model ``cfg``), pooled over the admitted
+    ones: the rest lie wholly above the diagonal and are skipped.  Read
+    from each request's bucket and the tiles ``kernels.ops.flash_tiles``
+    chooses there."""
+    dims = (cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim(),
+            jnp.dtype(cfg.compute_dtype).itemsize)
+    computed = total = 0
+    for r in requests:
+        if r.bucket is None:
+            continue
+        _, tiles = flash_tiles(r.bucket, r.bucket, *dims, causal=True)
+        if tiles is None:
+            continue
+        below, crossing, t = causal_tiles(r.bucket, r.bucket, *tiles)
+        computed, total = computed + below + crossing, total + t
+    return computed / total if total else 0.0
 
 
 class _GcSpans:

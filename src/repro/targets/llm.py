@@ -258,7 +258,9 @@ def _attention_schedule(key: "OpKey"):
         return None, f"degenerate query tile (S={S} < {MIN_QUERY_TILE})"
     # itemsize (not a name heuristic) so the recorded schedule matches the
     # one the kernel wrapper re-derives from q.dtype.itemsize
-    sched, tiles = kops.flash_tiles(S, T, H, K, hd, dtype_itemsize(key.dtype))
+    causal = key.op == "attention_causal"
+    sched, tiles = kops.flash_tiles(S, T, H, K, hd, dtype_itemsize(key.dtype),
+                                    causal)
     if tiles is None:
         return None, f"untileable shape S={S} T={T} H={H} K={K}"
     bq, bk = tiles
@@ -315,15 +317,19 @@ DOMAIN = DomainPackage(
             evaluator=_np_flash_attention,
             trace_kind="attention",
             trace_program=_attention_program,
-            ops=("attention", "attention_decode", "attention_paged"),
+            ops=("attention", "attention_decode", "attention_paged",
+                 "attention_causal"),
             rewrites=("softmax-shift", "matvec-scale-right"),
             scheduler=_attention_schedule,
             kernel=kops.flash_attention_gqa,
             kernel_pipelined=flash_attention_pipelined,
             chunked=_ATTN_CHUNKED,
-            op_notes=(("attention", "prefill"),
+            op_notes=(("attention", "prefix/full mask (dense operand)"),
                       ("attention_decode", "1-row query → reference"),
-                      ("attention_paged", "1-row query → reference")),
+                      ("attention_paged", "1-row query → reference"),
+                      ("attention_causal", "causal prefill/training: index "
+                                           "mask, tiles above the diagonal "
+                                           "skipped")),
             description="Row-blocked GQA flash attention.",
         ),
         IsaxSpec(

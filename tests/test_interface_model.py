@@ -210,12 +210,19 @@ class TestKernelSynth:
         skinny = choose_matmul_blocks(8, 4096, 8192, dtype_bytes=1)
         assert skinny.pipelined and skinny.buffering > 2
 
-    def test_flash_blocks_prefer_streaming_kv(self):
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_flash_blocks_prefer_streaming_kv(self, causal):
         from repro.core.kernel_synth import choose_flash_blocks
-        s = choose_flash_blocks(4096, 4096, 128)
+        from repro.core.tiling import causal_tiles
+        s = choose_flash_blocks(4096, 4096, 128, causal=causal)
         assert s.decisions["kv_hint"] == "cold"
         assert s.decisions["q_hint"] == "warm"
         assert s.vmem_bytes <= 64 * 1024 * 1024
+        if causal:  # costed over the tiles at or below the diagonal
+            below, crossing, total = causal_tiles(
+                4096, 4096, s.block("q")[0], s.block("kv")[0])
+            assert s.decisions["tiles_run"] == f"{below + crossing}/{total}"
+            assert below + crossing < total
 
     def test_ssd_blocks(self):
         from repro.core.kernel_synth import choose_ssd_blocks

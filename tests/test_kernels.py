@@ -1,10 +1,13 @@
 """Per-kernel shape/dtype sweeps vs the pure-jnp oracles (interpret mode)."""
 
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.tiling import causal_tiles
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.int8_matmul import int8_matmul
@@ -40,6 +43,70 @@ def test_flash_attention_sweep(B, S, H, K, T, hd, dtype, mask_kind):
     want = ref.flash_attention_ref(q, k, v, mask, sm_scale=hd ** -0.5)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
+
+
+@pytest.mark.parametrize("B,S,H,K,T,hd,bq,bk", [
+    (1, 256, 4, 4, 256, 64, 64, 64),      # S = T, square tiles
+    (1, 256, 4, 2, 256, 64, 128, 32),     # tall tiles: the diagonal spans 4
+    (1, 256, 2, 2, 256, 128, 32, 128),    # wide tiles
+    (1, 128, 2, 2, 256, 128, 32, 64),     # T > S: offset T − S = 128
+    (2, 128, 4, 2, 384, 64, 64, 64),      # T > S: offset T − S = 256
+    (1, 256, 8, 1, 256, 128, 64, 64),     # GQA 8:1
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_causal(B, S, H, K, T, hd, bq, bk, dtype):
+    """The causal path (index mask, tiles above the diagonal skipped) equals
+    the oracle under the causal mask aligned to the last key; the dense
+    mask operand is not read, so an all-True one changes nothing."""
+    q = jnp.asarray(RNG.normal(size=(B, S, H, hd)), dtype)
+    k = jnp.asarray(RNG.normal(size=(B, T, K, hd)), dtype)
+    v = jnp.asarray(RNG.normal(size=(B, T, K, hd)), dtype)
+    mask = jnp.tril(jnp.ones((S, T), bool), k=T - S)[None]
+    below, crossing, total = causal_tiles(S, T, bq, bk)
+    assert below + crossing < total            # some tiles are skipped
+    out = flash_attention(q, k, v, jnp.ones((1, S, T), bool),
+                          sm_scale=hd ** -0.5, block_q=bq, block_k=bk,
+                          causal=True, interpret=True)
+    want = ref.flash_attention_ref(q, k, v, mask, sm_scale=hd ** -0.5)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32), **_tol(dtype))
+
+
+@pytest.mark.parametrize("S,T,bq,bk", [
+    (256, 256, 64, 64), (256, 256, 128, 32), (128, 384, 64, 128),
+    (1024, 1024, 1024, 128), (4096, 4096, 512, 1024),
+])
+def test_causal_tiles_match_the_diagonal(S, T, bq, bk):
+    """``causal_tiles`` counts, tile by tile, those wholly at or below the
+    diagonal ``col <= row + T − S`` and those it crosses."""
+    mask = np.tril(np.ones((S, T), bool), k=T - S)
+    tiles = mask.reshape(S // bq, bq, T // bk, bk).transpose(0, 2, 1, 3)
+    below = int(tiles.all(axis=(2, 3)).sum())
+    ran = int(tiles.any(axis=(2, 3)).sum())
+    assert causal_tiles(S, T, bq, bk) == (below, ran - below,
+                                          (S // bq) * (T // bk))
+
+
+@pytest.mark.parametrize("buckets", [(1024,), (1024, 2048, 4096, 4096),
+                                     (16, 32)])
+def test_flash_tile_share_counts_tiles_at_or_below_the_diagonal(buckets):
+    """``flash_tile_share`` pools, over the admitted requests, the tiles of
+    the kernel's grid at each request's bucket that hold any visible key."""
+    from repro.configs.base import ModelConfig
+    from repro.serve.telemetry import flash_tile_share
+    cfg = ModelConfig(name="t", family="dense", vocab=64, d_model=4096,
+                      n_layers=1, n_heads=32, n_kv_heads=4, d_ff=128,
+                      head_dim=128)
+    reqs = [types.SimpleNamespace(bucket=b) for b in buckets]
+    reqs.append(types.SimpleNamespace(bucket=None))   # not yet admitted
+    ran = total = 0
+    for b in buckets:
+        _, (bq, bk) = ops.flash_tiles(b, b, 32, 4, 128, 2, causal=True)
+        grid = np.tril(np.ones((b, b), bool)).reshape(
+            b // bq, bq, b // bk, bk).any(axis=(1, 3))
+        ran, total = ran + int(grid.sum()), total + grid.size
+    assert flash_tile_share(reqs, cfg) == pytest.approx(ran / total)
+    assert flash_tile_share(reqs[-1:], cfg) == 0.0
 
 
 def test_flash_attention_fully_masked_rows():
@@ -141,9 +208,11 @@ def test_ops_wrappers_choose_synthesized_blocks():
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
 
 
-def test_layers_pallas_interpret_path_matches_xla():
+@pytest.mark.parametrize("causal", [False, True])
+def test_layers_pallas_interpret_path_matches_xla(causal):
     """models.layers attention with a pallas_interpret LoweringConfig == the
-    xla-reference lowering (kernel choice through the compile dispatcher)."""
+    xla-reference lowering (kernel choice through the compile dispatcher),
+    on the dense-mask and on the causal path."""
     from repro.compile import Dispatcher, LoweringConfig
     from repro.models import layers as L
     from repro.configs.registry import get_config
@@ -156,8 +225,12 @@ def test_layers_pallas_interpret_path_matches_xla():
     pos = jnp.broadcast_to(jnp.arange(128)[None], (2, 128))
     disp = Dispatcher()
     want, _ = L.attention(p, x, cfg, mask, pos,
-                          lowering=LoweringConfig("xla", disp))
+                          lowering=LoweringConfig("xla", disp),
+                          causal=causal)
     got, _ = L.attention(p, x, cfg, mask, pos,
-                         lowering=LoweringConfig("pallas_interpret", disp))
+                         lowering=LoweringConfig("pallas_interpret", disp),
+                         causal=causal)
+    op = "attention_causal" if causal else "attention"
+    assert [k.op for k, r in disp.records.items() if r.impl == "isax"] == [op]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=1e-4)

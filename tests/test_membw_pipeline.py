@@ -38,7 +38,10 @@ def _tol(dtype):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("depth", [2, 3])
-def test_flash_pipelined_parity(dtype, depth):
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_pipelined_parity(dtype, depth, causal):
+    """Pipelined == BlockSpec kernel on the same path: the dense mask, or
+    the causal index mask with the sweep stopped at the diagonal."""
     B, S, H, K, T, hd = 2, 128, 4, 2, 256, 64
     q = jnp.asarray(RNG.normal(size=(B, S, H, hd)), dtype)
     k = jnp.asarray(RNG.normal(size=(B, T, K, hd)), dtype)
@@ -46,10 +49,11 @@ def test_flash_pipelined_parity(dtype, depth):
     mask = jnp.broadcast_to(jnp.tril(jnp.ones((S, T), bool), k=T - S)[None],
                             (1, S, T))
     got = flash_attention_pipelined(q, k, v, mask, sm_scale=hd ** -0.5,
-                                    block_q=64, block_k=64, depth=depth,
-                                    interpret=True)
+                                    block_q=64, block_k=64, causal=causal,
+                                    depth=depth, interpret=True)
     want = flash_attention(q, k, v, mask, sm_scale=hd ** -0.5,
-                           block_q=64, block_k=64, interpret=True)
+                           block_q=64, block_k=64, causal=causal,
+                           interpret=True)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
 

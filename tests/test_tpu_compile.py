@@ -13,8 +13,10 @@ do not compile yet are ``xfail(strict=True)`` with the compiler's error, so
 the change that repairs one has to flip its mark.
 """
 
+import json
 import math
 import os
+import pathlib
 import re
 
 import jax
@@ -27,6 +29,8 @@ from repro.configs.registry import get_config
 from repro.kernels import ops as kops
 from repro.models.registry import get_model
 from repro.pointcloud import ops as pcops
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 
 @pytest.fixture(scope="module")
@@ -92,6 +96,42 @@ def test_prefill_program_compiles_at_bucket_128(one_chip):
     text = (jax.jit(model.prefill_at).lower(params, {"tokens": tokens}, length)
             .compile().as_text())
     assert "tpu_custom_call" in text
+
+
+def test_causal_prefill_keeps_the_traced_kernel_signature(one_chip):
+    """Yi-9B's widths cut to one layer, bf16, prefilled at bucket 2048: the
+    attention runs the causal flash kernel, whose operands q, k, v and the
+    int32 diagonal offset (no S×T mask) still read as the benchmark's trace
+    pattern for the flash kernel (``perfbench/trace_names.json``)."""
+    from perfbench.tracereduce import compact
+    cfg = ModelConfig(name="yi9b-1l", family="dense", n_layers=1,
+                      d_model=4096, n_heads=32, n_kv_heads=4, d_ff=11008,
+                      vocab=64000, head_dim=128, remat="none")
+    disp = Dispatcher()
+    model = get_model(cfg, lowering=LoweringConfig("pallas", disp))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.key(0)))
+    tokens = jax.ShapeDtypeStruct((1, 2048), I32, sharding=one_chip)
+    length = jax.ShapeDtypeStruct((), I32, sharding=one_chip)
+    compiled = jax.jit(model.prefill_at).lower(
+        params, {"tokens": tokens}, length).compile()
+    assert any(k.op == "attention_causal" and r.impl == "isax"
+               for k, r in disp.records.items())
+    # The profiler names an op by its HLO text with operand shapes, which
+    # ``as_text()`` leaves out.
+    from jaxlib._jax import HloPrintOptions
+    opts = HloPrintOptions.short_parsable()
+    opts.print_operand_shape = True
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+    names = json.loads((ROOT / "perfbench" / "trace_names.json").read_text())
+    calls = [ln.strip().lstrip("ROOT ") for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    flash = [c for c in calls
+             if any(all(part in compact(c) for part in pattern)
+                    for pattern in names["flash_kernel"])]
+    assert flash, [compact(c) for c in calls]
+    assert all("s32[1]" in c and "s32[1,2048,2048]" not in c for c in flash)
 
 
 def _unfused_ops(hlo_text):
